@@ -14,10 +14,14 @@ import sys
 import traceback
 
 
-def main() -> None:
+def main() -> int:
+    """Run every section; a failed section is recorded as
+    ``SECTION-FAILED`` and the others keep going, but the exit code
+    is 1."""
     from . import (engine_bench, kernels_bench, numa_sim, roofline_bench,
                    serving_bench)
     print("name,us_per_call,derived")
+    failed = []
     for mod in (numa_sim, engine_bench, serving_bench, kernels_bench,
                 roofline_bench):
         try:
@@ -26,7 +30,9 @@ def main() -> None:
         except Exception:  # noqa: BLE001 — keep other sections alive
             traceback.print_exc()
             print(f"{mod.__name__},0.0,SECTION-FAILED", file=sys.stderr)
+            failed.append(mod.__name__)
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

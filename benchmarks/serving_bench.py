@@ -744,6 +744,13 @@ def serving_tp_rows() -> List[Row]:
     import subprocess
     import sys
 
+    if jax.default_backend() != "cpu":
+        # a chip belongs to one process: this parent already holds it,
+        # so the child could not get it — and its forced host devices
+        # would say nothing about the chip anyway
+        raise RuntimeError(
+            "serving_tp runs its forced-host-device child on CPU hosts "
+            f"only (this process holds the {jax.default_backend()})")
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")

@@ -1,4 +1,4 @@
-"""qwen3-1.7b [dense] — qk_norm, GQA. [hf:Qwen/Qwen3-8B]"""
+"""qwen3-1.7b [dense] — qk_norm, GQA. [hf:Qwen/Qwen3-1.7B]"""
 
 import jax.numpy as jnp
 
@@ -20,5 +20,5 @@ CONFIG = ModelConfig(
     long_context="sliding_window",
     long_context_window=16_384,
     dtype=jnp.bfloat16,
-    source="hf:Qwen/Qwen3-8B",
+    source="hf:Qwen/Qwen3-1.7B",
 )

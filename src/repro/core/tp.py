@@ -43,7 +43,7 @@ from typing import Any, Callable, Dict, Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 
 # ----------------------------------------------------------------------
@@ -188,7 +188,7 @@ def make_tp_block(mesh: Mesh, kind: str, *, axis: str = "model",
 
     in_specs = ({w: plan.spec_for(w) for w in wnames}, P())
     return shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=P(),
-                     check_rep=False)
+                     check_vma=False)
 
 
 # ----------------------------------------------------------------------

@@ -3,17 +3,21 @@
 The decode hot-spot: a single query attends over a long KV cache —
 pure HBM bandwidth (read every cache byte once), exactly the workload
 ArcLight's NUMA placement targets.  TPU adaptation: the cache streams
-HBM→VMEM in (BS, D) chunks along the sequence grid axis; online
-softmax state (m, l, acc) lives in VMEM scratch across grid steps;
-the final grid step normalises and writes out.
+HBM→VMEM in (BS, H, D) chunks along the sequence grid axis; online
+softmax state (m, l, acc) per kv head lives in VMEM scratch across grid
+steps; the final grid step normalises and writes out.
 
 Shapes (GQA folded outside the kernel by the ops wrapper):
     q   (B, H, G, D)   one token's queries, G = Hq // Hkv
     k,v (B, S, H, D)   cache (H = kv heads)
     kv_len scalar      number of valid cache slots (rest masked)
 
-Grid: (B, H, S/BS) — the sequence axis is innermost so scratch
-accumulates per (batch, head).
+Grid: (B, S/BS) — the sequence axis is innermost so scratch
+accumulates per batch row.  Every block spans the whole kv-head axis:
+Mosaic tiles the last two dims of a block, and a block of one head out
+of H (``(BS, 1, D)``) is neither a multiple of the sublane tile nor the
+array's full extent, so the chip's compiler refuses it.  The kernel
+walks the H heads of a block with a static loop instead.
 """
 
 from __future__ import annotations
@@ -30,42 +34,69 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 
+def _online_softmax_heads(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref,
+                          mask, *, scale: float, softcap: float = 0.0):
+    """One KV block's online-softmax update for every kv head.
+
+    ``q_ref`` (1, H, G, D), ``k_ref``/``v_ref`` (1, BS, H, D), scratch
+    ``acc`` (H, G, D) and ``m``/``l`` (H, G, 1); ``mask`` (1, BS) marks
+    the block's visible slots.  The MXU takes q, k, v in their own
+    dtype and the probabilities in V's, accumulating in f32 — the
+    operands ``models.attention.flash_attention`` uses."""
+    for h in range(q_ref.shape[1]):
+        q = q_ref[0, h]                                # (G, D)
+        k = k_ref[0, :, h, :]                          # (BS, D)
+        v = v_ref[0, :, h, :]                          # (BS, D)
+        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+        if softcap:
+            s = jnp.tanh(s / softcap) * softcap
+        s = jnp.where(mask, s, NEG_INF)                # (G, BS)
+
+        m_prev = m_ref[h]                              # (G, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[h] = l_ref[h] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[h] = acc_ref[h] * alpha + jnp.dot(
+            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+        m_ref[h] = m_new
+
+
+def _init_scratch(acc_ref, m_ref, l_ref):
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+
+
+def _write_out(o_ref, acc_ref, l_ref):
+    denom = l_ref[...]
+    denom = jnp.where(denom > 0, denom, 1.0)
+    o_ref[0] = acc_ref[...] / denom
+
+
+def _scratch(H: int, G: int, D: int):
+    return [pltpu.VMEM((H, G, D), jnp.float32),
+            pltpu.VMEM((H, G, 1), jnp.float32),
+            pltpu.VMEM((H, G, 1), jnp.float32)]
+
+
 def _decode_attn_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
                         acc_ref, m_ref, l_ref, *,
                         block_s: int, n_s: int, scale: float):
-    s_idx = pl.program_id(2)
+    s_idx = pl.program_id(1)
 
     @pl.when(s_idx == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+        _init_scratch(acc_ref, m_ref, l_ref)
 
-    q = q_ref[0, 0].astype(jnp.float32)            # (G, D)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)      # (BS, D)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)      # (BS, D)
-
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale  # (G,BS)
-    kv_len = len_ref[0]
     kpos = s_idx * block_s + jax.lax.broadcasted_iota(
         jnp.int32, (1, block_s), 1)
-    s = jnp.where(kpos < kv_len, s, NEG_INF)
-
-    m_prev = m_ref[...]                            # (G, 1)
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-    p = jnp.exp(s - m_new)                         # (G, BS)
-    alpha = jnp.exp(m_prev - m_new)                # (G, 1)
-    l_new = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
-        p, v, preferred_element_type=jnp.float32)
-    m_ref[...] = m_new
-    l_ref[...] = l_new
+    _online_softmax_heads(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref,
+                          kpos < len_ref[0], scale=scale)
 
     @pl.when(s_idx == n_s - 1)
     def _finalize():
-        denom = l_ref[...]
-        denom = jnp.where(denom > 0, denom, 1.0)
-        o_ref[0, 0] = acc_ref[...] / denom
+        _write_out(o_ref, acc_ref, l_ref)
 
 
 def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -85,18 +116,14 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                                n_s=n_s, scale=scale)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,                     # kv_len in SMEM
-        grid=(B, H, n_s),
+        grid=(B, n_s),
         in_specs=[
-            pl.BlockSpec((1, 1, G, D), lambda b, h, s, _: (b, h, 0, 0)),
-            pl.BlockSpec((1, block_s, 1, D), lambda b, h, s, _: (b, s, h, 0)),
-            pl.BlockSpec((1, block_s, 1, D), lambda b, h, s, _: (b, s, h, 0)),
+            pl.BlockSpec((1, H, G, D), lambda b, s, _: (b, 0, 0, 0)),
+            pl.BlockSpec((1, block_s, H, D), lambda b, s, _: (b, s, 0, 0)),
+            pl.BlockSpec((1, block_s, H, D), lambda b, s, _: (b, s, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, G, D), lambda b, h, s, _: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((G, D), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec((1, H, G, D), lambda b, s, _: (b, 0, 0, 0)),
+        scratch_shapes=_scratch(H, G, D),
     )
     return pl.pallas_call(
         kernel,
@@ -115,22 +142,13 @@ def _paged_decode_attn_kernel(bt_ref, len_ref, win_ref, q_ref, k_ref, v_ref,
                               o_ref, acc_ref, m_ref, l_ref, *,
                               page_size: int, n_pages: int, scale: float,
                               softcap: float):
-    p_idx = pl.program_id(2)
+    b = pl.program_id(0)
+    p_idx = pl.program_id(1)
 
     @pl.when(p_idx == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+        _init_scratch(acc_ref, m_ref, l_ref)
 
-    b = pl.program_id(0)
-    q = q_ref[0, 0].astype(jnp.float32)            # (G, D)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)      # (ps, D) — one page
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
-
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-    if softcap:
-        s = jnp.tanh(s / softcap) * softcap
     kv_len = len_ref[b]
     window = win_ref[0]
     # logical (not physical) positions of this page's slots
@@ -138,23 +156,12 @@ def _paged_decode_attn_kernel(bt_ref, len_ref, win_ref, q_ref, k_ref, v_ref,
         jnp.int32, (1, page_size), 1)
     mask = kpos < kv_len
     mask &= (window <= 0) | (kpos > kv_len - 1 - window)
-    s = jnp.where(mask, s, NEG_INF)
-
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-    p = jnp.exp(s - m_new)
-    alpha = jnp.exp(m_prev - m_new)
-    l_new = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
-        p, v, preferred_element_type=jnp.float32)
-    m_ref[...] = m_new
-    l_ref[...] = l_new
+    _online_softmax_heads(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, mask,
+                          scale=scale, softcap=softcap)
 
     @pl.when(p_idx == n_pages - 1)
     def _finalize():
-        denom = l_ref[...]
-        denom = jnp.where(denom > 0, denom, 1.0)
-        o_ref[0, 0] = acc_ref[...] / denom
+        _write_out(o_ref, acc_ref, l_ref)
 
 
 def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
@@ -166,11 +173,14 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
 
     ``k_pages``/``v_pages`` are the paged view of ONE layer's flat pool
     buffer — the ops wrapper (``repro.kernels.ops``) reshapes the
-    per-layer (P*ps, H, D) cache buffer before dispatching here.
+    per-layer (P*ps, H, D) cache buffer before dispatching here.  H is
+    whatever the pool holds: all kv heads, or one shard's slice of them
+    under the head-sharded serving mesh.
     ``block_tables`` (B, max_pages) int32 and ``kv_lens`` (B,) int32 are
     scalar-prefetched so each grid step's BlockSpec index_map can DMA the
     *physical* page the sequence's logical page j maps to — the gather
     never materialises a contiguous copy of the sequence's cache.
+    Grid (B, max_pages): one step DMAs one page of every kv head.
     """
     B, H, G, D = q.shape
     P, page_size, _, _ = k_pages.shape
@@ -185,22 +195,18 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
                                scale=scale, softcap=softcap)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,        # block tables, kv lens, window
-        grid=(B, H, max_pages),
+        grid=(B, max_pages),
         in_specs=[
-            pl.BlockSpec((1, 1, G, D),
-                         lambda b, h, p, bt, ln, w: (b, h, 0, 0)),
-            pl.BlockSpec((1, page_size, 1, D),
-                         lambda b, h, p, bt, ln, w: (bt[b, p], 0, h, 0)),
-            pl.BlockSpec((1, page_size, 1, D),
-                         lambda b, h, p, bt, ln, w: (bt[b, p], 0, h, 0)),
+            pl.BlockSpec((1, H, G, D),
+                         lambda b, p, bt, ln, w: (b, 0, 0, 0)),
+            pl.BlockSpec((1, page_size, H, D),
+                         lambda b, p, bt, ln, w: (bt[b, p], 0, 0, 0)),
+            pl.BlockSpec((1, page_size, H, D),
+                         lambda b, p, bt, ln, w: (bt[b, p], 0, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, G, D),
-                               lambda b, h, p, bt, ln, w: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((G, D), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec((1, H, G, D),
+                               lambda b, p, bt, ln, w: (b, 0, 0, 0)),
+        scratch_shapes=_scratch(H, G, D),
     )
     return pl.pallas_call(
         kernel,

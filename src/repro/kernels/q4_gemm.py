@@ -37,9 +37,11 @@ def _q4_gemm_kernel(x_ref, packed_ref, scales_ref, out_ref, *, n_k: int):
     """One (BN, BK) tile: unpack, dequant, matmul, accumulate."""
     k = pl.program_id(1)
 
-    packed = packed_ref[...]                       # (BK//2, BN) uint8
-    lo = (packed & 0x0F).astype(jnp.int8) - 8
-    hi = (packed >> 4).astype(jnp.int8) - 8
+    # widen before unpacking: Mosaic does not legalize 8-bit integer
+    # arithmetic, so the nibble maths runs on int32 lanes
+    packed = packed_ref[...].astype(jnp.int32)     # (BK//2, BN)
+    lo = (packed & 0x0F) - 8
+    hi = (packed >> 4) - 8
     codes = jnp.stack([lo, hi], axis=1)            # (BK//2, 2, BN)
     bk2, _, bn = codes.shape
     codes = codes.reshape(2 * bk2, bn)             # (BK, BN)

@@ -28,7 +28,8 @@ def decode_attention_ref(q: jax.Array, k: jax.Array, v: jax.Array,
     mask = jnp.arange(S) < jnp.asarray(kv_len)
     s = jnp.where(mask[None, None, None, :], s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bhgs,bshd->bhgd", p, v.astype(jnp.float32))
+    return jnp.einsum("bhgs,bshd->bhgd", p.astype(v.dtype), v,
+                      preferred_element_type=jnp.float32)
 
 
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
@@ -78,7 +79,8 @@ def paged_decode_attention_ref(q: jax.Array, k_pages: jax.Array,
     valid &= (w <= 0) | (j[None, :] > qpos - w)
     s = jnp.where(valid[:, None, None, :], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bhgs,bshd->bhgd", p, v.astype(jnp.float32))
+    return jnp.einsum("bhgs,bshd->bhgd", p.astype(v.dtype), v,
+                      preferred_element_type=jnp.float32)
 
 
 def rglru_scan_ref(a: jax.Array, u: jax.Array, h0=None) -> jax.Array:

@@ -36,8 +36,7 @@ def _rglru_scan_kernel(a_ref, u_ref, o_ref, h_ref, *, block_t: int):
 
     def body(i, h):
         h = a_ref[:, i, :] * h + u_ref[:, i, :]
-        pl.store(o_ref, (slice(None), pl.dslice(i, 1), slice(None)),
-                 h[:, None, :])
+        o_ref[:, pl.ds(i, 1), :] = h[:, None, :].astype(o_ref.dtype)
         return h
 
     h_ref[...] = jax.lax.fori_loop(0, block_t, body, h_ref[...])

@@ -12,26 +12,19 @@ parallelism (2 pods × 256 chips).
 
 from __future__ import annotations
 
+import math
 from typing import Tuple
 
 import jax
-from jax.sharding import Mesh
-
-try:  # jax >= 0.5 has explicit mesh axis types
-    from jax.sharding import AxisType
-except ImportError:  # older jax: every axis is implicitly Auto
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
-    """``jax.make_mesh`` with Auto axis types where supported.
-
-    Single compat point for the whole repo — older jax releases have no
-    ``axis_types`` kwarg (all axes behave as Auto there anyway)."""
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with Auto axis types over the first
+    ``prod(shape)`` devices."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=jax.devices()[:math.prod(shape)])
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

@@ -1,9 +1,11 @@
 """Serving launcher: ``python -m repro.launch.serve --arch <id>``.
 
 Brings up the decoding frontend on a REDUCED variant of the assigned
-architecture (CPU host), optionally warm-trains it briefly so greedy
-output isn't pure noise, then serves a batch of byte-level prompts and
-prints the throughput report (the paper's §4 measurement protocol).
+architecture (CPU host) — or, with ``--full-config``, on the arch at
+its published widths and dtype (accelerator host, random weights) —
+optionally warm-trains a reduced model briefly so greedy output isn't
+pure noise, then serves a batch of byte-level prompts and prints the
+throughput report (the paper's §4 measurement protocol).
 
 ``--engine bucket`` (default) is the sequential length-bucket baseline;
 ``--engine continuous`` runs the paged-KV continuous-batching engine
@@ -41,7 +43,6 @@ Examples:
 """
 
 import argparse
-import dataclasses
 import sys
 
 
@@ -211,6 +212,11 @@ def main() -> int:
     ap.add_argument("--warmup-steps", type=int, default=40,
                     help="brief LM warm-up so outputs aren't noise "
                          "(0 = random weights)")
+    ap.add_argument("--full-config", action="store_true",
+                    help="serve the arch at its published widths and "
+                         "dtype (accelerator hosts; needs "
+                         "--warmup-steps 0) instead of its reduced "
+                         "float32 variant")
     ap.add_argument("--metrics-json", metavar="PATH", default=None,
                     help="paged engines: write the metrics registry "
                          "snapshot (JSON, repro.obs schema) on exit")
@@ -266,6 +272,13 @@ def main() -> int:
         ap.error("--spec-decode must be >= 0")
     if args.replicas and not args.http:
         ap.error("--replicas needs --http")
+    if args.full_config and args.warmup_steps:
+        ap.error("--full-config serves random weights: AdamW warm-up "
+                 "of the full model is training, not serving — pass "
+                 "--warmup-steps 0")
+    if args.full_config and args.replicas:
+        ap.error("--replicas workers serve reduced variants; "
+                 "--full-config applies to --replicas 0")
     if args.max_respawns < 0:
         ap.error("--max-respawns must be >= 0")
     if args.http:
@@ -284,43 +297,39 @@ def main() -> int:
     import time
 
     if args.tp_shards > 1:
-        # must land before the first jax import: device count is fixed
-        # at backend initialisation
+        # sizes the CPU backend only (an accelerator backend ignores
+        # it); must land before the first jax import: the CPU device
+        # count is fixed at backend initialisation
         os.environ["XLA_FLAGS"] = (
             os.environ.get("XLA_FLAGS", "")
             + f" --xla_force_host_platform_device_count={args.tp_shards}")
 
     import jax
-    import jax.numpy as jnp
 
-    from ..configs import get_config, list_archs
+    from ..configs import list_archs
     from ..data.pipeline import (PackedLMDataset, stub_frames,
                                  stub_image_embeds)
     from ..data.tokenizer import ByteTokenizer
-    from ..models import build_model, reduced_config
     from ..serving import (AsyncEngine, ContinuousServingEngine, Request,
                            ServingEngine, throughput_report)
     from ..serving.sampler import SamplingParams
+    from ..serving.worker import build_serving_model
     from ..training.loop import train
     from ..training.optimizer import AdamWConfig
+    from .compile_cache import enable_compile_cache
 
-    if args.arch == "tiny":
-        # the benchmark suite's bench-tiny model: instant to build, the
-        # smoke-test arch for --http
-        from ..serving.worker import build_tiny
-        model, params = build_tiny()
-        cfg = model.cfg
-    elif args.arch not in list_archs():
+    if args.arch != "tiny" and args.arch not in list_archs():
         ap.error(f"unknown arch; choose 'tiny' or one of {list_archs()}")
-    else:
-        cfg = reduced_config(get_config(args.arch))
-        cfg = dataclasses.replace(cfg, dtype=jnp.float32,
-                                  capacity_factor=4.0,
-                                  vocab_size=max(cfg.vocab_size, 259))
-        model = build_model(cfg)
-        params = model.init(jax.random.PRNGKey(0))
+    enable_compile_cache()
+    # 'tiny' is the benchmark suite's bench-tiny model: instant to
+    # build, the smoke-test arch for --http
+    model, params = build_serving_model(args.arch,
+                                        full_config=args.full_config)
+    cfg = model.cfg
     tok = ByteTokenizer()
-    print(f"arch={cfg.name} (reduced, {cfg.param_count() / 1e6:.1f}M)")
+    print(f"arch={cfg.name} "
+          f"({'full' if args.full_config else 'reduced'}, "
+          f"{cfg.param_count() / 1e6:.1f}M)")
 
     mesh = None
     if args.tp_shards > 1:
@@ -328,14 +337,16 @@ def main() -> int:
             ap.error("--tp-shards serves through the paged engines; "
                      "use --engine continuous or async")
         from .mesh import make_mesh
-        if len(jax.devices()) < args.tp_shards:
-            ap.error(f"{len(jax.devices())} devices for "
-                     f"--tp-shards {args.tp_shards} (XLA_FLAGS was set "
-                     "too late — is jax imported before main()?)")
+        devs = jax.devices()
+        if len(devs) < args.tp_shards:
+            hint = ("XLA_FLAGS was set too late — is jax imported "
+                    "before main()?" if devs[0].platform == "cpu" else
+                    "this host has fewer chips")
+            ap.error(f"{len(devs)} {devs[0].platform} devices for "
+                     f"--tp-shards {args.tp_shards} ({hint})")
         mesh = make_mesh((args.tp_shards,), ("model",))
         print(f"tp mesh: {args.tp_shards}-way 'model' axis over "
-              f"{[d.platform for d in jax.devices()][0]} devices "
-              "(shard ≅ NUMA node)")
+              f"{devs[0].platform} devices (shard ≅ NUMA node)")
 
     if args.warmup_steps:
         print(f"warm-up training ({args.warmup_steps} steps) ...")

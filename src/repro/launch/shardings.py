@@ -179,7 +179,7 @@ def make_moe_hook(cfg: ModelConfig, mesh: Mesh, policy: Policy, *,
     n_model = mesh.shape.get("model", 1)
     if cfg.d_ff % n_model:
         return None
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from ..models.moe import moe as moe_fn
 
     ep = policy.expert_parallel and cfg.n_experts % n_model == 0
@@ -211,7 +211,7 @@ def make_moe_hook(cfg: ModelConfig, mesh: Mesh, policy: Policy, *,
 
     def hook(moe_params, x):
         return shard_map(body, mesh=mesh, in_specs=(w_specs, x_spec),
-                         out_specs=(x_spec, P()), check_rep=False)(
+                         out_specs=(x_spec, P()), check_vma=False)(
                              moe_params, x)
 
     return hook
@@ -333,7 +333,7 @@ def make_decode_attn_hook(cfg: ModelConfig, mesh: Mesh, policy: Policy, *,
     n_shards = 1
     for a in seq_axes:
         n_shards *= mesh.shape[a]
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from ..models.attention import combine_partials, flash_attention
 
     local = cache_len // n_shards
@@ -377,7 +377,7 @@ def make_decode_attn_hook(cfg: ModelConfig, mesh: Mesh, policy: Policy, *,
             in_specs=(q_spec, q_spec, q_spec, seq_spec, seq_spec, P(),
                       P(), P()),
             out_specs=(q_spec, seq_spec, seq_spec, P()),
-            check_rep=False)(q, kn, vn, ck, cv, cp, window, pos)
+            check_vma=False)(q, kn, vn, ck, cv, cp, window, pos)
         ck = jax.lax.with_sharding_constraint(ck, seq_ns)
         cv = jax.lax.with_sharding_constraint(cv, seq_ns)
         return out, ck, cv, cp

@@ -53,10 +53,12 @@ def main() -> int:
     from ..training.loop import make_train_step
     from ..training.optimizer import AdamWConfig, adamw_init
     from ..training.checkpoint import save_checkpoint
+    from .compile_cache import enable_compile_cache
     from .mesh import make_host_mesh
 
     if args.arch not in list_archs():
         ap.error(f"unknown arch; choose from {list_archs()}")
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if not args.full_config:
         cfg = reduced_config(cfg)

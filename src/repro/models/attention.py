@@ -129,8 +129,11 @@ def flash_attention(
         p = jnp.exp(s - m_new[..., None])
         alpha = jnp.exp(m - m_new)
         l_new = lsum * alpha + jnp.sum(p, axis=-1)
-        pv = jnp.einsum("bqhgc,bchd->bqhgd", p,
-                        vci.astype(jnp.float32))
+        # probabilities enter the PV matmul in V's dtype (f32 accumulate),
+        # as in the Pallas decode kernel: both paths then feed the MXU
+        # the same operands whatever the default matmul precision
+        pv = jnp.einsum("bqhgc,bchd->bqhgd", p.astype(vci.dtype), vci,
+                        preferred_element_type=jnp.float32)
         out_new = out * alpha[..., None] + pv
         return (out_new, m_new, l_new), None
 

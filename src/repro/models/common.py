@@ -113,9 +113,11 @@ def mlp(params: Params, x: jax.Array, act: str, qmm=None) -> jax.Array:
 
 def unembed(embed_or_head: jax.Array, x: jax.Array, *,
             tied: bool) -> jax.Array:
-    if tied:
-        return x @ embed_or_head.T
-    return x @ embed_or_head
+    """Logits in float32 whatever the model dtype: rounded to bf16, the
+    top two of a 150k-entry vocab tie exactly at a few percent of
+    positions, and greedy argmax then follows rounding noise."""
+    head = embed_or_head.T if tied else embed_or_head
+    return jnp.matmul(x, head, preferred_element_type=jnp.float32)
 
 
 def cross_entropy(logits: jax.Array, labels: jax.Array,

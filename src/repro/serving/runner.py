@@ -175,7 +175,7 @@ class ModelRunner:
     # tensor-parallel mode
     # ------------------------------------------------------------------
     def _init_tp(self, policy: Optional[Any]) -> None:
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         from ..launch.shardings import (make_paged_head_merge,
@@ -229,7 +229,7 @@ class ModelRunner:
             lambda p, c, t, pos: self.local_model.decode_step(
                 p, c, t, pos, page_size=ps, window_override=wo),
             mesh=mesh, in_specs=(self._pspecs, self._cspecs, P(), P()),
-            out_specs=(P(), self._cspecs), check_rep=False)
+            out_specs=(P(), self._cspecs), check_vma=False)
         self._decode = jax.jit(self.tp_raw_decode, donate_argnums=1)
         self._copy_rows = jax.jit(
             shard_map(
@@ -237,7 +237,7 @@ class ModelRunner:
                     lambda a: a.at[dst].set(a[src]), layers),
                 mesh=mesh,
                 in_specs=(self._cspecs["layers"], P(), P()),
-                out_specs=self._cspecs["layers"], check_rep=False),
+                out_specs=self._cspecs["layers"], check_vma=False),
             donate_argnums=0)
 
     # ------------------------------------------------------------------
@@ -261,7 +261,7 @@ class ModelRunner:
                         window_override=self.window_override),
                     donate_argnums=2)
             return self._prefill_jits[key]
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         ps, wo, local = self.page_size, self.window_override, \
             self.local_model
@@ -280,7 +280,7 @@ class ModelRunner:
                         P(), P())
         self._prefill_jits[key] = jax.jit(
             shard_map(body, mesh=self.mesh, in_specs=in_specs,
-                      out_specs=(P(), self._cspecs), check_rep=False),
+                      out_specs=(P(), self._cspecs), check_vma=False),
             donate_argnums=2)
         return self._prefill_jits[key]
 
@@ -300,7 +300,7 @@ class ModelRunner:
                     window_override=self.window_override),
                 donate_argnums=1)
         else:
-            from jax.experimental.shard_map import shard_map
+            from jax import shard_map
             from jax.sharding import PartitionSpec as P
             ps, wo, local = self.page_size, self.window_override, \
                 self.local_model
@@ -311,7 +311,7 @@ class ModelRunner:
                         window_override=wo),
                     mesh=self.mesh,
                     in_specs=(self._pspecs, self._cspecs, P(), P(), P()),
-                    out_specs=(P(), self._cspecs), check_rep=False),
+                    out_specs=(P(), self._cspecs), check_vma=False),
                 donate_argnums=1)
         self._verify_jits[S] = fn
         return fn
@@ -380,6 +380,14 @@ class ModelRunner:
         if self._c_q4_prefill is not None:
             self._c_q4_prefill.inc()
         return logits
+
+    def decode_program_text(self) -> str:
+        """HLO text of the compiled decode step, as the device runs it
+        (a Pallas kernel shows as ``tpu_custom_call``)."""
+        fed = jnp.zeros((self.max_running, 1), jnp.int32)
+        pos = jnp.zeros((self.max_running,), jnp.int32)
+        return self._decode.lower(self.params, self.cache, fed,
+                                  pos).compile().as_text()
 
     def decode(self, fed: np.ndarray, pos: np.ndarray) -> jax.Array:
         """One batched decode step: ``fed`` (max_running, 1) tokens,

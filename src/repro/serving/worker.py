@@ -45,6 +45,31 @@ def build_tiny(seed: int = 0):
     return model, model.init(jax.random.PRNGKey(seed))
 
 
+def build_serving_model(arch: str, *, full_config: bool = False,
+                        seed: int = 0):
+    """The model a serving entry point runs, with params from
+    ``PRNGKey(seed)``: ``'tiny'`` (:func:`build_tiny`), a registry arch
+    at its published widths and dtype (``full_config``), or by default
+    its REDUCED float32 variant for CPU hosts."""
+    if arch == "tiny":
+        return build_tiny(seed)
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+
+    from ..configs import get_config
+    from ..models import build_model, reduced_config
+    cfg = get_config(arch)
+    if not full_config:
+        cfg = reduced_config(cfg)
+        cfg = dataclasses.replace(cfg, dtype=jnp.float32,
+                                  capacity_factor=4.0,
+                                  vocab_size=max(cfg.vocab_size, 259))
+    model = build_model(cfg)
+    return model, model.init(jax.random.PRNGKey(seed))
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--host", default="127.0.0.1")
@@ -70,21 +95,9 @@ def main(argv=None) -> int:
     ap.add_argument("--token-timeout", type=float, default=120.0)
     args = ap.parse_args(argv)
 
-    if args.arch == "tiny":
-        model, params = build_tiny(args.seed)
-    else:
-        import dataclasses
-
-        import jax
-        import jax.numpy as jnp
-
-        from ..configs import get_config
-        from ..models import build_model, reduced_config
-        cfg = reduced_config(get_config(args.arch))
-        cfg = dataclasses.replace(cfg, dtype=jnp.float32,
-                                  vocab_size=max(cfg.vocab_size, 259))
-        model = build_model(cfg)
-        params = model.init(jax.random.PRNGKey(args.seed))
+    from ..launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+    model, params = build_serving_model(args.arch, seed=args.seed)
 
     from ..data.tokenizer import ByteTokenizer
     from . import faults

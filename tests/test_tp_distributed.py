@@ -89,7 +89,7 @@ def test_seq_sharded_flash_decode_combine():
     print(_run("""
         import jax, jax.numpy as jnp, numpy as np, functools
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from repro.models.attention import (flash_attention,
                                             combine_partials)
         from repro.launch.mesh import make_mesh
@@ -112,7 +112,7 @@ def test_seq_sharded_flash_decode_combine():
         fn = shard_map(body, mesh=mesh,
                        in_specs=(P(), P(None, "data", None, None),
                                  P(None, "data", None, None)),
-                       out_specs=P(), check_rep=False)
+                       out_specs=P(), check_vma=False)
         out = fn(jnp.asarray(q), jnp.asarray(k), jnp.asarray(v))
         assert np.allclose(np.asarray(out), np.asarray(full),
                            atol=1e-5), np.abs(np.asarray(out)-np.asarray(full)).max()

@@ -69,10 +69,11 @@ doc_check() {
     done
 
     # 3. CLI flags like --prefill-chunk must appear in some source file
-    #    under src/, benchmarks/ or tools/ (argparse / script flags)
+    #    under src/, benchmarks/, tools/ or chip_smoke.py (argparse /
+    #    script flags)
     for flag in $(grep -hoE '(^|[^-])--[a-z][a-z0-9-]+' "${docs[@]}" \
                       | grep -oE '\-\-[a-z][a-z0-9-]+' | sort -u); do
-        if ! grep -rqF -- "\"$flag\"" src benchmarks tools; then
+        if ! grep -rqF -- "\"$flag\"" src benchmarks tools chip_smoke.py; then
             echo "doc-check: flag $flag in docs but not in any CLI"
             fail=1
         fi
