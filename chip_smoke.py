@@ -293,6 +293,7 @@ def main(argv=None) -> int:
 
     from repro.launch.compile_cache import enable_compile_cache
     from repro.serving.worker import build_serving_model
+    say("lines marked 'smoke:' are bring-up output, not metrics")
     say(f"compile cache {enable_compile_cache()}")
     say(f"device {devices[0].device_kind} x{len(devices)}")
     log = CompileLog()
