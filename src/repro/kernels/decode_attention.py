@@ -213,4 +213,8 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, G, D), jnp.float32),
         interpret=interpret,
+        # the kernel's instruction name in the compiled program and the
+        # device trace (``%paged_gqa_decode_attention.N``), whatever
+        # wrappers it is called through
+        name="paged_gqa_decode_attention",
     )(block_tables, kv_lens, window, q, k_pages, v_pages)

@@ -21,6 +21,13 @@ annotations    ``PREFILL_CHUNK`` (one per chunk: ``start``/``n``
                applied before this request's chunk resumed)
 =============  ========================================================
 
+The same module names the engine's **step spans**: host spans the
+serving layers open around their own work with
+``jax.profiler.TraceAnnotation(name, **stats)`` (:data:`STEP_SPANS`),
+always on, so a profiler session finds them on the device trace's
+clock; and the ``jax.named_scope`` names the runner's compiled
+programs carry (:data:`PROGRAM_SCOPES`).
+
 Export is JSONL — one ``{"uid":…, "event":…, "t":…, …attrs}`` object
 per line, keyed by request uid (:meth:`RequestTracer.to_jsonl`) —
 chosen over a nested document so a long-running server can append and
@@ -39,6 +46,53 @@ from __future__ import annotations
 import json
 import threading
 from typing import Dict, IO, Iterable, List, Optional, Tuple
+
+# -- step spans (docs/observability.md "Step spans") ------------------
+#: the async stepper's wait for work (the device idles for want of
+#: requests)
+SPAN_PARK = "serving.park"
+#: the async stepper's cancels, inbox drain and ``core.submit``
+SPAN_ADMIT = "serving.admit"
+#: the async stepper's token delivery, ``on_token`` callbacks included
+SPAN_PUBLISH = "serving.publish"
+#: one ``EngineCore.step``; every span below is its child
+SPAN_STEP = "serving.step"
+SPAN_SCHEDULE = "serving.schedule"
+#: queued copy-on-write page copies (only on a step that applies some)
+SPAN_COW_COPY = "serving.cow_copy"
+#: block-table rebuild and upload
+SPAN_SYNC_TABLES = "serving.sync_tables"
+#: one prefill chunk's dispatch
+SPAN_PREFILL_CHUNK = "serving.prefill_chunk"
+#: the batched decode's dispatch
+SPAN_DECODE = "serving.decode"
+#: the speculative verify's dispatch
+SPAN_VERIFY = "serving.verify"
+#: one sampling call, its blocking device-to-host read included
+SPAN_SAMPLE = "serving.sample"
+#: every step span and the stats it carries
+STEP_SPANS: Dict[str, Tuple[str, ...]] = {
+    SPAN_PARK: (),
+    SPAN_ADMIT: ("n_new", "n_cancel"),
+    SPAN_PUBLISH: ("tokens",),
+    SPAN_STEP: ("prefills", "lanes", "queued", "pages_used",
+                "pages_reserved"),
+    SPAN_SCHEDULE: (),
+    SPAN_COW_COPY: (),
+    SPAN_SYNC_TABLES: (),
+    SPAN_PREFILL_CHUNK: ("start", "n", "fresh"),
+    SPAN_DECODE: ("lanes", "kv_tokens"),
+    SPAN_VERIFY: ("lanes", "fed"),
+    SPAN_SAMPLE: (),
+}
+#: ``jax.named_scope`` of each compiled serving program's body, as the
+#: device operations' ``op_name`` metadata shows it (the programs keep
+#: their jit names)
+SCOPE_DECODE = "serving.decode"
+SCOPE_PREFILL = "serving.prefill"
+SCOPE_VERIFY = "serving.verify"
+SCOPE_COW_COPY = "serving.cow_copy"
+PROGRAM_SCOPES = (SCOPE_DECODE, SCOPE_PREFILL, SCOPE_VERIFY, SCOPE_COW_COPY)
 
 STATE_EVENTS = ("QUEUED", "PREFILLING", "DECODING", "FINISHED",
                 "CANCELLED", "FAILED")

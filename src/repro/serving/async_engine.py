@@ -30,8 +30,18 @@ stepper's exception — background threads must never swallow errors.
 
 The stepper **parks** (condition-variable wait) whenever the core has
 no work and the inbox is empty: an idle engine costs zero CPU, and
-``submit`` wakes it.  ``shutdown()`` stops the loop, joins the thread,
-and cancels whatever was still in flight.
+``submit`` wakes it.  Its three phases around ``core.step`` are the
+``serving.park`` / ``serving.admit`` / ``serving.publish`` step spans
+(``repro.obs.trace.STEP_SPANS``).
+
+Latency counts from the client's ``submit``: the handle's
+``t_submit`` stamp (engine clock) is what the ``QUEUED`` trace event,
+``Completion.t0`` and ``deadline_s`` start from, however long the
+request sat in the inbox before the stepper drained it, and
+``t_scheduled`` is its first slot grant.
+
+``shutdown()`` stops the loop, joins the thread, and cancels whatever
+was still in flight.
 """
 
 from __future__ import annotations
@@ -42,7 +52,10 @@ import itertools
 import threading
 from typing import Any, Callable, Dict, Iterator, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 from ..models.transformer import Model
+from ..obs.trace import SPAN_ADMIT, SPAN_PARK, SPAN_PUBLISH
 from .core import Clock, EngineCore
 from .engine import Completion, Request
 from .scheduler import Sequence
@@ -93,6 +106,10 @@ class RequestHandle:                # one in-flight request, never a value
     #: per-token push callback (``submit(on_token=)``) — invoked by the
     #: stepper thread OUTSIDE the engine lock, once per sampled token
     on_token: Optional[Callable[[int], None]] = None
+    #: engine-clock stamp of ``submit``
+    t_submit: float = 0.0
+    #: engine-clock time of the first slot grant (None until granted)
+    t_scheduled: Optional[float] = None
     _seq: Optional[Sequence] = None          # set once the stepper admits
     _n_polled: int = 0
 
@@ -182,7 +199,7 @@ class AsyncEngine:
             uid = next(self._uids)
             handle = RequestHandle(
                 uid=uid, request=dataclasses.replace(request, uid=uid),
-                on_token=on_token)
+                on_token=on_token, t_submit=self.core.clock.now())
             self._handles[uid] = handle
             self._inbox.append(handle)
             self._c_submitted.inc()
@@ -286,48 +303,64 @@ class AsyncEngine:
         try:
             while True:
                 with self._wake:
-                    while (self._alive and not self._inbox
-                           and not self._cancels and not core.has_work()):
-                        self._wake.wait()       # park: idle engine = 0 CPU
+                    if self._alive and self._idle():
+                        with TraceAnnotation(SPAN_PARK):
+                            while self._alive and self._idle():
+                                self._wake.wait()   # idle engine = 0 CPU
                     if not self._alive:
                         return
-                    inbox, self._inbox = self._inbox, []
-                    cancels, self._cancels = self._cancels, []
-                    self._g_inbox.set(len(inbox))
-                for handle in cancels:
-                    if handle.done:     # finished/failed while queued
-                        continue        # for cancel: keep that state
-                    if handle._seq is not None:
-                        core.cancel(handle._seq)
-                    self._c_cancelled.inc()
-                    with self._update:
-                        handle.state = RequestState.CANCELLED
-                        self._handles.pop(handle.uid, None)
-                        self._update.notify_all()
-                now = core.clock.now() - self._clock0
-                for handle in inbox:
-                    if handle.done:             # cancelled while queued
-                        continue
-                    try:
-                        handle._seq = core.submit(handle.request,
-                                                  arrival=now)
-                    except ValueError as e:     # bad request, engine fine
-                        # never reached core.submit's QUEUED stamp: give
-                        # the trace a complete (if instant) lifecycle
-                        t = core.clock.now()
-                        core.tracer.event(handle.uid, "QUEUED", t)
-                        core.tracer.event(handle.uid, "FAILED", t,
-                                          error=str(e))
-                        self._c_failed.inc()
-                        with self._update:
-                            handle.state = RequestState.FAILED
-                            handle.error = e
-                            self._handles.pop(handle.uid, None)
-                            self._update.notify_all()
+                with TraceAnnotation(SPAN_ADMIT) as span:
+                    self._admit(span)
                 res = core.step(now=core.clock.now() - self._clock0)
-                self._publish(res)
+                with TraceAnnotation(SPAN_PUBLISH,
+                                     tokens=len(res.emitted)):
+                    self._publish(res)
         except BaseException as e:              # noqa: BLE001 — must
             self._die(e)                        # reach the callers
+
+    def _idle(self) -> bool:
+        return not (self._inbox or self._cancels
+                    or self.core.has_work())
+
+    def _admit(self, span: TraceAnnotation) -> None:
+        """Apply cancels, then hand the inbox to the core in submission
+        order, each request arriving at its own ``submit`` stamp."""
+        core = self.core
+        with self._wake:
+            inbox, self._inbox = self._inbox, []
+            cancels, self._cancels = self._cancels, []
+            self._g_inbox.set(len(inbox))
+        span.set_metadata(n_new=len(inbox), n_cancel=len(cancels))
+        for handle in cancels:
+            if handle.done:     # finished/failed while queued
+                continue        # for cancel: keep that state
+            if handle._seq is not None:
+                core.cancel(handle._seq)
+            self._c_cancelled.inc()
+            with self._update:
+                handle.state = RequestState.CANCELLED
+                self._handles.pop(handle.uid, None)
+                self._update.notify_all()
+        for handle in inbox:
+            if handle.done:             # cancelled while queued
+                continue
+            try:
+                # t0 - arrival = clock0, the offset _finish relies on
+                handle._seq = core.submit(
+                    handle.request, t0=handle.t_submit,
+                    arrival=handle.t_submit - self._clock0)
+            except ValueError as e:     # bad request, engine fine
+                # never reached core.submit's QUEUED stamp: give the
+                # trace a complete (if instant) lifecycle
+                core.tracer.event(handle.uid, "QUEUED", handle.t_submit)
+                core.tracer.event(handle.uid, "FAILED", core.clock.now(),
+                                  error=str(e))
+                self._c_failed.inc()
+                with self._update:
+                    handle.state = RequestState.FAILED
+                    handle.error = e
+                    self._handles.pop(handle.uid, None)
+                    self._update.notify_all()
 
     def _publish(self, res) -> None:
         callbacks: List[tuple] = []
@@ -375,6 +408,8 @@ class AsyncEngine:
                 if handle.done or handle._seq is None:
                     continue
                 seq = handle._seq
+                if handle.t_scheduled is None and seq.t_first_sched >= 0:
+                    handle.t_scheduled = self._clock0 + seq.t_first_sched
                 if seq.slot < 0:
                     handle.state = RequestState.QUEUED
                 elif seq.is_prefilling:

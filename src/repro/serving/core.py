@@ -31,10 +31,14 @@ from typing import Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..models.transformer import Model
 from ..obs.metrics import MetricsRegistry, NullRegistry
-from ..obs.trace import NullTracer, RequestTracer
+from ..obs.trace import (SPAN_COW_COPY, SPAN_DECODE, SPAN_PREFILL_CHUNK,
+                         SPAN_SAMPLE, SPAN_SCHEDULE, SPAN_STEP,
+                         SPAN_SYNC_TABLES, SPAN_VERIFY, NullTracer,
+                         RequestTracer)
 from . import faults
 from .engine import Completion, Request
 from .kv_pool import KVCachePool, KVPoolConfig
@@ -183,7 +187,7 @@ class EngineCore:
             model, params, max_running=max_running, max_len=max_len,
             page_size=page_size, n_pages=n_pages,
             window_override=window_override, mesh=mesh, policy=policy,
-            quant=quant, registry=self.registry, clock=self.clock)
+            quant=quant, registry=self.registry)
 
         self._meta: Dict[int, Dict[str, object]] = {}  # uid -> timing stamps
         self._t_last_decode: Optional[float] = None
@@ -196,7 +200,10 @@ class EngineCore:
         reg = self.registry
         self._m_phase_prefill = reg.counter(
             "serving.phase.prefill_s",
-            "wall seconds spent running prefill chunks (run-scoped)")
+            "host wall seconds from each prefill chunk's dispatch to its "
+            "return, the final chunk's sample included; non-final "
+            "chunks are not synced, so this is host time, not device "
+            "time (run-scoped)")
         self._m_phase_decode = reg.counter(
             "serving.phase.decode_s",
             "wall seconds spent in batched decode (run-scoped)")
@@ -207,9 +214,6 @@ class EngineCore:
             "inter-token latency: wall gap between consecutive decode "
             "steps (run-scoped)")
         self._h_itl = self._m_itl.labels()
-        self._h_chunk = reg.histogram(
-            "serving.prefill.chunk_ms",
-            "one prefill chunk end-to-end (dispatch + sample)").labels()
         self._c_steps = reg.counter(
             "serving.steps", "engine steps, idle included").labels()
         self._c_tok_prefill = reg.counter(
@@ -361,11 +365,13 @@ class EngineCore:
     # ------------------------------------------------------------------
     def _sync_tables(self) -> None:
         """Host block tables -> device cache array."""
-        bt = np.zeros((self.max_running, self.runner.max_pages), np.int32)
-        for slot, seq in self.scheduler.running.items():
-            pages = self.pool.block_table(seq.uid)
-            bt[slot, :len(pages)] = pages
-        self.runner.set_block_tables(bt)
+        with TraceAnnotation(SPAN_SYNC_TABLES):
+            bt = np.zeros((self.max_running, self.runner.max_pages),
+                          np.int32)
+            for slot, seq in self.scheduler.running.items():
+                pages = self.pool.block_table(seq.uid)
+                bt[slot, :len(pages)] = pages
+            self.runner.set_block_tables(bt)
 
     def _apply_copies(self) -> None:
         """Apply the pool's queued copy-on-write page copies to the
@@ -375,19 +381,20 @@ class EngineCore:
         copies = self.pool.drain_copies()
         if not copies:
             return
-        if self.tracer.enabled:
-            # attribute cloned destination pages back to owning uids
-            # (only walks block tables on the rare CoW step)
-            t = self.clock.now()
-            dsts = {d for _, d in copies}
-            for seq in self.scheduler.running.values():
-                n = sum(1 for p in self.pool.block_table(seq.uid)
-                        if p in dsts)
-                if n:
-                    self.tracer.event(seq.uid, "COW", t, pages=n)
-        src, dst = self.pool.copy_row_plan(
-            copies, pad_to_pages=_pad_bucket(len(copies), lo=1))
-        self.runner.apply_copy_rows(src, dst)
+        with TraceAnnotation(SPAN_COW_COPY):
+            if self.tracer.enabled:
+                # attribute cloned destination pages back to owning uids
+                # (only walks block tables on the rare CoW step)
+                t = self.clock.now()
+                dsts = {d for _, d in copies}
+                for seq in self.scheduler.running.values():
+                    n = sum(1 for p in self.pool.block_table(seq.uid)
+                            if p in dsts)
+                    if n:
+                        self.tracer.event(seq.uid, "COW", t, pages=n)
+            src, dst = self.pool.copy_row_plan(
+                copies, pad_to_pages=_pad_bucket(len(copies), lo=1))
+            self.runner.apply_copy_rows(src, dst)
 
     def _finish(self, seq: Sequence) -> Completion:
         m = self._meta.pop(seq.uid)
@@ -412,13 +419,24 @@ class EngineCore:
     def step(self, now: float = 0.0) -> StepResult:
         """One engine step: schedule, apply CoW copies, run prefill
         chunks, run the batched decode, sample, finish.  ``now`` gates
-        admission of waiting arrivals (driver-relative seconds)."""
+        admission of waiting arrivals (driver-relative seconds).  The
+        whole step is the ``serving.step`` span (``repro.obs.trace``)."""
+        with TraceAnnotation(SPAN_STEP) as span:
+            return self._step(now, span)
+
+    def _step(self, now: float, span: TraceAnnotation) -> StepResult:
         clock = self.clock
         tracer = self.tracer
         if faults.ACTIVE:       # injected worker latency (chaos tests)
             faults.maybe_sleep("step.latency_ms")
         self._c_steps.inc()
-        plan = self.scheduler.step(now)
+        with TraceAnnotation(SPAN_SCHEDULE):
+            plan = self.scheduler.step(now)
+        span.set_metadata(prefills=len(plan.prefills),
+                          lanes=len(plan.decodes),
+                          queued=len(self.scheduler.waiting),
+                          pages_used=self.pool.n_live(),
+                          pages_reserved=self.n_pages - 1)
         for seq in plan.expired:
             # scheduler already drained slot + pages; surface the death
             # through the normal terminal vocabulary so trace validation
@@ -454,14 +472,17 @@ class EngineCore:
                 tracer.event(seq.uid, "PREFILLING", t0, start=start,
                              cached=seq.n_cached_tokens)
             tracer.event(seq.uid, "PREFILL_CHUNK", t0, start=start, n=n)
-            logits = self.runner.prefill_chunk(
-                prompt[start:start + n], slot=seq.slot, start=start,
-                fresh=fresh)
+            with TraceAnnotation(SPAN_PREFILL_CHUNK, start=start, n=n,
+                                 fresh=fresh):
+                logits = self.runner.prefill_chunk(
+                    prompt[start:start + n], slot=seq.slot, start=start,
+                    fresh=fresh)
             seq.n_prefilled += n
             if not seq.is_prefilling:           # final chunk: sample
-                tok = int(np.asarray(sample(
-                    logits, seq.request.sampling,
-                    self._next_key()))[0, 0])
+                with TraceAnnotation(SPAN_SAMPLE):
+                    tok = int(np.asarray(sample(
+                        logits, seq.request.sampling,
+                        self._next_key()))[0, 0])
                 seq.generated.append(tok)
                 res.emitted.append((seq.uid, tok))
                 # prompt KV is resident now — index it for reuse
@@ -471,7 +492,6 @@ class EngineCore:
                 tracer.event(seq.uid, "DECODING", clock.now())
             dt = clock.now() - t0
             self._c_prefill_s.inc(dt)
-            self._h_chunk.observe(dt * 1e3)
             self._c_tok_prefill.inc(n)
             m["prefill"] = m.get("prefill", 0.0) + dt
             if not seq.is_prefilling and seq.is_done(self.max_len):
@@ -502,12 +522,17 @@ class EngineCore:
                 # slots
                 sps = [plan.decodes[0].request.sampling] \
                     * self.max_running
+                kv_tokens = 0
                 for seq in plan.decodes:
                     pos[seq.slot] = seq.next_pos - 1  # fed-token position
                     fed[seq.slot, 0] = seq.generated[-1]
                     sps[seq.slot] = seq.request.sampling
-                logits = self.runner.decode(fed, pos)
-                toks = sample_grouped(logits, sps, self._next_key())
+                    kv_tokens += seq.next_pos
+                with TraceAnnotation(SPAN_DECODE, lanes=len(plan.decodes),
+                                     kv_tokens=kv_tokens):
+                    logits = self.runner.decode(fed, pos)
+                with TraceAnnotation(SPAN_SAMPLE):
+                    toks = sample_grouped(logits, sps, self._next_key())
                 for seq in plan.decodes:
                     tok = int(toks[seq.slot, 0])
                     seq.generated.append(tok)
@@ -563,11 +588,14 @@ class EngineCore:
                 fed[seq.slot, 1:1 + len(ds)] = ds
                 n_fed[seq.slot] = 1 + len(ds)
             sps[seq.slot] = seq.request.sampling
-        logits = self.runner.verify(fed, pos, n_fed)
-        # the model's greedy choice at every fed position — same
-        # argmax (same tie-breaking) sample() runs for greedy lanes
-        targets = np.asarray(jnp.argmax(logits, axis=-1))   # (B, S)
-        toks = sample_grouped(logits[:, :1], sps, self._next_key())
+        with TraceAnnotation(SPAN_VERIFY, lanes=len(plan.decodes),
+                             fed=int(n_fed[pos >= 0].sum())):
+            logits = self.runner.verify(fed, pos, n_fed)
+        with TraceAnnotation(SPAN_SAMPLE):
+            # the model's greedy choice at every fed position — same
+            # argmax (same tie-breaking) sample() runs for greedy lanes
+            targets = np.asarray(jnp.argmax(logits, axis=-1))  # (B, S)
+            toks = sample_grouped(logits[:, :1], sps, self._next_key())
         n_emitted = 0
         for seq in plan.decodes:
             ds = drafts.get(seq.slot)

@@ -38,7 +38,6 @@ runner/sampling boundary.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Any, Dict, Optional, Sequence, Tuple
 
 import jax
@@ -46,6 +45,19 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models.transformer import Model
+from ..obs.trace import (SCOPE_COW_COPY, SCOPE_DECODE, SCOPE_PREFILL,
+                         SCOPE_VERIFY)
+
+
+def _scoped(scope: str, fn):
+    """``fn`` traced under ``jax.named_scope(scope)``, so the device
+    trace's op metadata names the program's work
+    (``repro.obs.trace.PROGRAM_SCOPES``).  The wrapper is a lambda: a
+    jitted program keeps the name ``jit__lambda``."""
+    def call(args):
+        with jax.named_scope(scope):
+            return fn(*args)
+    return lambda *args: call(args)
 
 
 def _pad_bucket(n: int, lo: int = 8) -> int:
@@ -84,8 +96,7 @@ class ModelRunner:
                  mesh: Optional[Any] = None,
                  policy: Optional[Any] = None,
                  quant: Optional[Any] = None,
-                 registry: Optional[Any] = None,
-                 clock: Optional[Any] = None) -> None:
+                 registry: Optional[Any] = None) -> None:
         from ..quant.policy import (QuantPolicy, make_qmm,
                                     quantize_serving_params)
         self.model = model
@@ -111,29 +122,8 @@ class ModelRunner:
         self.tp_axis = "model"
         self.tp_shards = (int(mesh.shape.get(self.tp_axis, 1))
                           if mesh is not None else 1)
-        # observability: per-call dispatch time (enqueue-to-return of
-        # the compiled call — device completion is owned by whoever
-        # blocks; under TP one shard_map dispatch drives all S shards,
-        # so series are labelled by shard count).  Instruments resolve
-        # once; time comes from the engine's injected clock so tests
-        # under a VirtualClock record zeros deterministically.
-        self._now = clock.now if clock is not None else time.perf_counter
-        self._h_decode = self._h_prefill = self._h_verify = None
         self._c_q4_decode = self._c_q4_prefill = self._c_q4_verify = None
         if registry is not None:
-            shards = str(self.tp_shards)
-            self._h_decode = registry.histogram(
-                "runner.decode.dispatch_ms",
-                "batched decode dispatch wall per call").labels(
-                    shards=shards)
-            self._h_prefill = registry.histogram(
-                "runner.prefill.dispatch_ms",
-                "prefill-chunk dispatch wall per call").labels(
-                    shards=shards)
-            self._h_verify = registry.histogram(
-                "runner.verify.dispatch_ms",
-                "speculative verify dispatch wall per call").labels(
-                    shards=shards)
             if self.quant.weights == "q4":
                 # dequant dispatch counters: each compiled forward under
                 # Q4_0 weights routes every projection through the
@@ -157,18 +147,20 @@ class ModelRunner:
         if mesh is not None:
             self._init_tp(policy)
             return
-        self._decode = jax.jit(
+        self._decode = jax.jit(_scoped(
+            SCOPE_DECODE,
             lambda p, c, t, pos: model.decode_step(
                 p, c, t, pos, page_size=page_size,
-                window_override=window_override),
+                window_override=window_override)),
             donate_argnums=1)
         # batched CoW page copier over the per-layer buffer list: one
         # donated gather+scatter moves every queued page in-place on
         # every layer (un-jitted .at[].set would copy each buffer once
         # per page); row counts bucket so compiles stay few
-        self._copy_rows = jax.jit(
+        self._copy_rows = jax.jit(_scoped(
+            SCOPE_COW_COPY,
             lambda layers, src, dst: jax.tree.map(
-                lambda a: a.at[dst].set(a[src]), layers),
+                lambda a: a.at[dst].set(a[src]), layers)),
             donate_argnums=0)
 
     # ------------------------------------------------------------------
@@ -230,14 +222,17 @@ class ModelRunner:
                 p, c, t, pos, page_size=ps, window_override=wo),
             mesh=mesh, in_specs=(self._pspecs, self._cspecs, P(), P()),
             out_specs=(P(), self._cspecs), check_vma=False)
-        self._decode = jax.jit(self.tp_raw_decode, donate_argnums=1)
-        self._copy_rows = jax.jit(
+        self._decode = jax.jit(_scoped(SCOPE_DECODE,
+                                       self.tp_raw_decode),
+                               donate_argnums=1)
+        self._copy_rows = jax.jit(_scoped(
+            SCOPE_COW_COPY,
             shard_map(
                 lambda layers, src, dst: jax.tree.map(
                     lambda a: a.at[dst].set(a[src]), layers),
                 mesh=mesh,
                 in_specs=(self._cspecs["layers"], P(), P()),
-                out_specs=self._cspecs["layers"], check_vma=False),
+                out_specs=self._cspecs["layers"], check_vma=False)),
             donate_argnums=0)
 
     # ------------------------------------------------------------------
@@ -247,18 +242,20 @@ class ModelRunner:
             return self._prefill_jits[key]
         if self.mesh is None:
             if ctx_pages:
-                self._prefill_jits[key] = jax.jit(
+                self._prefill_jits[key] = jax.jit(_scoped(
+                    SCOPE_PREFILL,
                     lambda p, b, c, slot, plen, start:
                     self.model.prefill_paged(
                         p, b, c, slot, plen, start=start,
                         ctx_pages=ctx_pages, page_size=self.page_size,
-                        window_override=self.window_override),
+                        window_override=self.window_override)),
                     donate_argnums=2)
             else:
-                self._prefill_jits[key] = jax.jit(
+                self._prefill_jits[key] = jax.jit(_scoped(
+                    SCOPE_PREFILL,
                     lambda p, b, c, slot, plen: self.model.prefill_paged(
                         p, b, c, slot, plen, page_size=self.page_size,
-                        window_override=self.window_override),
+                        window_override=self.window_override)),
                     donate_argnums=2)
             return self._prefill_jits[key]
         from jax import shard_map
@@ -278,9 +275,10 @@ class ModelRunner:
                 p, b, c, slot, plen, page_size=ps, window_override=wo))
             in_specs = (self._pspecs, {"tokens": P()}, self._cspecs,
                         P(), P())
-        self._prefill_jits[key] = jax.jit(
+        self._prefill_jits[key] = jax.jit(_scoped(
+            SCOPE_PREFILL,
             shard_map(body, mesh=self.mesh, in_specs=in_specs,
-                      out_specs=(P(), self._cspecs), check_vma=False),
+                      out_specs=(P(), self._cspecs), check_vma=False)),
             donate_argnums=2)
         return self._prefill_jits[key]
 
@@ -294,24 +292,26 @@ class ModelRunner:
         if fn is not None:
             return fn
         if self.mesh is None:
-            fn = jax.jit(
+            fn = jax.jit(_scoped(
+                SCOPE_VERIFY,
                 lambda p, c, t, pos, nf: self.model.verify_step(
                     p, c, t, pos, nf, page_size=self.page_size,
-                    window_override=self.window_override),
+                    window_override=self.window_override)),
                 donate_argnums=1)
         else:
             from jax import shard_map
             from jax.sharding import PartitionSpec as P
             ps, wo, local = self.page_size, self.window_override, \
                 self.local_model
-            fn = jax.jit(
+            fn = jax.jit(_scoped(
+                SCOPE_VERIFY,
                 shard_map(
                     lambda p, c, t, pos, nf: local.verify_step(
                         p, c, t, pos, nf, page_size=ps,
                         window_override=wo),
                     mesh=self.mesh,
                     in_specs=(self._pspecs, self._cspecs, P(), P(), P()),
-                    out_specs=(P(), self._cspecs), check_vma=False),
+                    out_specs=(P(), self._cspecs), check_vma=False)),
                 donate_argnums=1)
         self._verify_jits[S] = fn
         return fn
@@ -325,12 +325,9 @@ class ModelRunner:
         Returns (max_running, S, V) — column j's argmax is what plain
         decode would emit after j accepted drafts (see
         ``Model.verify_step``)."""
-        t0 = self._now() if self._h_verify is not None else 0.0
         logits, self.cache = self._verify_fn(fed.shape[1])(
             self.params, self.cache, jnp.asarray(fed), jnp.asarray(pos),
             jnp.asarray(n_fed))
-        if self._h_verify is not None:
-            self._h_verify.observe((self._now() - t0) * 1e3)
         if self._c_q4_verify is not None:
             self._c_q4_verify.inc()
         return logits
@@ -362,7 +359,6 @@ class ModelRunner:
         toks = np.zeros((1, padded), np.int32)
         toks[0, :n] = tokens
         batch = {"tokens": jnp.asarray(toks)}
-        t0 = self._now() if self._h_prefill is not None else 0.0
         if fresh:
             logits, self.cache = self._prefill_fn(padded, 0)(
                 self.params, batch, self.cache,
@@ -375,8 +371,6 @@ class ModelRunner:
                 self.params, batch, self.cache,
                 jnp.asarray(slot, jnp.int32), jnp.asarray(n, jnp.int32),
                 jnp.asarray(start, jnp.int32))
-        if self._h_prefill is not None:
-            self._h_prefill.observe((self._now() - t0) * 1e3)
         if self._c_q4_prefill is not None:
             self._c_q4_prefill.inc()
         return logits
@@ -393,11 +387,8 @@ class ModelRunner:
         """One batched decode step: ``fed`` (max_running, 1) tokens,
         ``pos`` (max_running,) absolute fed-token positions (-1 = idle
         slot, masked + scratch-paged).  Returns (max_running, 1, V)."""
-        t0 = self._now() if self._h_decode is not None else 0.0
         logits, self.cache = self._decode(
             self.params, self.cache, jnp.asarray(fed), jnp.asarray(pos))
-        if self._h_decode is not None:
-            self._h_decode.observe((self._now() - t0) * 1e3)
         if self._c_q4_decode is not None:
             self._c_q4_decode.inc()
         return logits

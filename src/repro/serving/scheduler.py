@@ -176,8 +176,13 @@ class ContinuousScheduler:
         # observability (optional; instruments resolved once — the
         # scheduler stays jax-free, repro.obs is stdlib-only)
         self._m_preempt = self._m_admit = self._m_expired = None
-        self._g_queue = self._g_running = None
+        self._g_queue = self._g_running = self._h_queue_wait = None
         if registry is not None:
+            self._h_queue_wait = registry.histogram(
+                "serving.queue_wait_ms",
+                "arrival to first slot grant, observed at the grant "
+                "(the async engine's arrival is the client's submit)"
+            ).labels()
             self._m_preempt = registry.counter(
                 "scheduler.preemptions",
                 "recompute-style preemptions (pool pressure)").labels()
@@ -351,6 +356,8 @@ class ContinuousScheduler:
             seq.slot = slot
             if seq.t_first_sched < 0:
                 seq.t_first_sched = now
+                if self._h_queue_wait is not None:
+                    self._h_queue_wait.observe((now - seq.arrival) * 1e3)
             if self._m_admit is not None:
                 self._m_admit.inc()
             self.running[slot] = seq

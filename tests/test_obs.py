@@ -276,8 +276,9 @@ def test_phase_and_gap_accounting_matches_hand_stamps(tiny):
     reg = core.registry
     s, n = reg.histogram("serving.decode.itl_ms").value()
     assert (n, s) == (1, pytest.approx(DECODE_COST * 1e3))
-    s, n = reg.histogram("serving.prefill.chunk_ms").value()
-    assert (n, s) == (2, pytest.approx(2 * PREFILL_COST * 1e3))
+    # both granted a slot by the step at their arrival: no queue wait
+    s, n = reg.histogram("serving.queue_wait_ms").value()
+    assert (n, s) == (2, 0.0)
     assert reg.counter("serving.tokens.prefill").value() == 16
     assert reg.counter("serving.tokens.decode").value() == 4
     assert reg.counter("scheduler.admissions").value() == 2
@@ -316,8 +317,9 @@ def test_chunked_prefill_chunk_events_and_histogram(tiny):
     assert [e.t for e in chunks] == pytest.approx(
         [0.0, PREFILL_COST, 2 * PREFILL_COST])
     assert core.phase_s["prefill_s"] == pytest.approx(3 * PREFILL_COST)
-    s, n = core.registry.histogram("serving.prefill.chunk_ms").value()
-    assert (n, s) == (3, pytest.approx(3 * PREFILL_COST * 1e3))
+    # one slot grant for the whole chunked prefill, at arrival
+    s, n = core.registry.histogram("serving.queue_wait_ms").value()
+    assert (n, s) == (1, 0.0)
     names = [e.name for e in tracer.events(0)
              if e.name != "PREFILL_CHUNK"]
     assert names == ["QUEUED", "PREFILLING", "DECODING", "FINISHED"]
@@ -497,3 +499,35 @@ def test_async_engine_failure_and_obs(tiny):
     assert validate_events(tracer.events()) == []
     names = {e.name for e in tracer.events()}
     assert "FAILED" in names and "FINISHED" in names
+
+
+def test_async_stamps_count_from_submit(tiny):
+    """The QUEUED stamp, ``Completion.t0`` and the queue wait all count
+    from the client's ``submit``, not from the stepper's inbox drain."""
+    _cfg, model, params = tiny
+    tracer = RequestTracer()
+    eng = AsyncEngine(model, params, max_len=32, max_running=1,
+                      page_size=8, tracer=tracer)
+    try:
+        hs = [eng.submit(Request(uid=0, prompt=[1, 2, 3 + i],
+                                 sampling=SamplingParams(
+                                     max_new_tokens=3)))
+              for i in range(3)]
+        comps = [eng.result(h, timeout=120) for h in hs]
+    finally:
+        eng.shutdown()
+    for h, c in zip(hs, comps):
+        queued = [e.t for e in tracer.events(h.uid) if e.name == "QUEUED"]
+        assert queued == [h.t_submit] == [c.t0]
+        assert h.t_scheduled >= h.t_submit
+        assert c.t_sched == pytest.approx(h.t_scheduled, abs=1e-9)
+    # one lane: each later request got its slot only when the one
+    # before it had finished, in submission order
+    assert hs[0].t_submit <= hs[1].t_submit <= hs[2].t_submit
+    for prev, c in zip(comps, hs[1:]):
+        assert c.t_scheduled >= prev.t1
+    s, n = eng.registry.histogram("serving.queue_wait_ms").value()
+    assert n == 3
+    assert s == pytest.approx(
+        sum((h.t_scheduled - h.t_submit) * 1e3 for h in hs), abs=1e-6)
+    assert validate_events(tracer.events()) == []

@@ -74,6 +74,62 @@ def test_paged_decode_attention_compiles(one_chip, hkv):
     assert "tpu_custom_call" in text
 
 
+def _readers_kernel_patterns():
+    """The ``KERNEL`` pattern of each benchmark reader that finds the
+    paged kernel in a device trace by its instruction name, read from
+    the reader's source (importing it would import the benchmark)."""
+    import ast
+    from pathlib import Path
+    metrics = Path(__file__).resolve().parents[1] / "bench" / "metrics"
+    out = {}
+    for name in ("decode_step_ms", "paged_attn_roofline",
+                 "prefill_ms_per_ktok"):
+        src = (metrics / f"{name}.py").read_text().splitlines()
+        line = next(ln for ln in src if ln.startswith("KERNEL = "))
+        out[name] = ast.literal_eval(line.split("=", 1)[1].strip())
+    return out
+
+
+# the paged kernel's instruction must keep the name the trace readers
+# match, called directly, through another jitted wrapper, and inside a
+# named scope as the runner's decode program calls it
+@pytest.mark.parametrize("via", ["direct", "wrapper", "named_scope"])
+def test_paged_kernel_instruction_name(one_chip, via):
+    import re
+
+    from repro.kernels.decode_attention import paged_decode_attention
+    B, G, D, ps, max_pages, hkv = 8, 2, 128, 16, 64, 8
+    n_pages = 1 + B * max_pages
+
+    def kernel(q, kp, vp, bt, lens):
+        return paged_decode_attention(q, kp, vp, bt, lens, 0,
+                                      interpret=False)
+
+    wrapped = jax.jit(kernel)
+
+    def fn(q, kp, vp, bt, lens):
+        # scaled, so the kernel is not the program's ROOT instruction,
+        # as it is not in the decode program
+        if via == "direct":
+            return kernel(q, kp, vp, bt, lens) * 2.0
+        if via == "wrapper":
+            return wrapped(q, kp, vp, bt, lens) * 2.0
+        with jax.named_scope("serving.decode"):
+            return wrapped(q, kp, vp, bt, lens) * 2.0
+
+    text = _compiled_text(
+        fn, one_chip,
+        ((B, hkv, G, D), jnp.bfloat16),
+        ((n_pages, ps, hkv, D), jnp.bfloat16),
+        ((n_pages, ps, hkv, D), jnp.bfloat16),
+        ((B, max_pages), jnp.int32),
+        ((B,), jnp.int32))
+    lines = [ln.strip() for ln in text.splitlines()]
+    for name, pattern in _readers_kernel_patterns().items():
+        rx = re.compile(pattern)
+        assert sum(1 for ln in lines if rx.search(ln)) == 1, name
+
+
 @pytest.mark.parametrize("m", [1, 8])
 def test_q4_gemm_compiles(one_chip, m):
     from repro.kernels.q4_gemm import q4_gemm
