@@ -138,8 +138,21 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 # sequence block tables (the serving KV pool's device layout)
 # ----------------------------------------------------------------------
 
-def _paged_decode_attn_kernel(bt_ref, len_ref, win_ref, q_ref, k_ref, v_ref,
-                              o_ref, acc_ref, m_ref, l_ref, *,
+def _visible_pages(kv_lens, window, page_size: int, n_pages: int):
+    """Each lane's first and last logical page holding a slot visible to
+    its query at ``kv_len - 1``: slots ``[kv_len - window, kv_len)``
+    under a sliding window, ``[0, kv_len)`` without one.  A lane with no
+    visible slot (``kv_len`` 0) gets ``first == last == 0``."""
+    last = jnp.clip((kv_lens + page_size - 1) // page_size - 1, 0,
+                    n_pages - 1)
+    first = jnp.where(window > 0,
+                      jnp.maximum(kv_lens - window, 0) // page_size, 0)
+    return jnp.minimum(first, last), last
+
+
+def _paged_decode_attn_kernel(bt_ref, len_ref, win_ref, first_ref, last_ref,
+                              q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
+                              l_ref, *,
                               page_size: int, n_pages: int, scale: float,
                               softcap: float):
     b = pl.program_id(0)
@@ -151,13 +164,20 @@ def _paged_decode_attn_kernel(bt_ref, len_ref, win_ref, q_ref, k_ref, v_ref,
 
     kv_len = len_ref[b]
     window = win_ref[0]
-    # logical (not physical) positions of this page's slots
-    kpos = p_idx * page_size + jax.lax.broadcasted_iota(
-        jnp.int32, (1, page_size), 1)
-    mask = kpos < kv_len
-    mask &= (window <= 0) | (kpos > kv_len - 1 - window)
-    _online_softmax_heads(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, mask,
-                          scale=scale, softcap=softcap)
+
+    # pages outside [first, last] hold no visible slot: the index maps
+    # name the nearest visible page there, so no DMA is issued, and the
+    # scores are skipped; a lane with no visible slot skips every page
+    @pl.when((kv_len > 0) & (p_idx >= first_ref[b])
+             & (p_idx <= last_ref[b]))
+    def _attend():
+        # logical (not physical) positions of this page's slots
+        kpos = p_idx * page_size + jax.lax.broadcasted_iota(
+            jnp.int32, (1, page_size), 1)
+        mask = kpos < kv_len
+        mask &= (window <= 0) | (kpos > kv_len - 1 - window)
+        _online_softmax_heads(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref,
+                              mask, scale=scale, softcap=softcap)
 
     @pl.when(p_idx == n_pages - 1)
     def _finalize():
@@ -180,7 +200,12 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
     scalar-prefetched so each grid step's BlockSpec index_map can DMA the
     *physical* page the sequence's logical page j maps to — the gather
     never materialises a contiguous copy of the sequence's cache.
-    Grid (B, max_pages): one step DMAs one page of every kv head.
+    Grid (B, max_pages): one step reads one page of every kv head.  The
+    walk is bounded by each lane's visible pages, from ``kv_len`` and the
+    window (scalar-prefetched too): a step outside them names the page
+    its neighbour named, so the pipeline fetches nothing, and it computes
+    nothing.  A lane with ``kv_len`` 0 (an idle batch slot) scores no page
+    and returns 0.
     """
     B, H, G, D = q.shape
     P, page_size, _, _ = k_pages.shape
@@ -189,23 +214,26 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
     block_tables = jnp.asarray(block_tables, jnp.int32)
     kv_lens = jnp.asarray(kv_lens, jnp.int32).reshape(B)
     window = jnp.asarray(window, jnp.int32).reshape(1)
+    # once per call: in every grid step's index maps and body the
+    # integer divisions cost ~0.05 us a step on a v5e
+    first, last = _visible_pages(kv_lens, window[0], page_size, max_pages)
+
+    def page_map(b, p, bt, ln, w, lo, hi):
+        return (bt[b, jnp.clip(p, lo[b], hi[b])], 0, 0, 0)
 
     kernel = functools.partial(_paged_decode_attn_kernel,
                                page_size=page_size, n_pages=max_pages,
                                scale=scale, softcap=softcap)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,        # block tables, kv lens, window
+        # block tables, kv lens, window, first and last visible pages
+        num_scalar_prefetch=5,
         grid=(B, max_pages),
         in_specs=[
-            pl.BlockSpec((1, H, G, D),
-                         lambda b, p, bt, ln, w: (b, 0, 0, 0)),
-            pl.BlockSpec((1, page_size, H, D),
-                         lambda b, p, bt, ln, w: (bt[b, p], 0, 0, 0)),
-            pl.BlockSpec((1, page_size, H, D),
-                         lambda b, p, bt, ln, w: (bt[b, p], 0, 0, 0)),
+            pl.BlockSpec((1, H, G, D), lambda b, p, *_: (b, 0, 0, 0)),
+            pl.BlockSpec((1, page_size, H, D), page_map),
+            pl.BlockSpec((1, page_size, H, D), page_map),
         ],
-        out_specs=pl.BlockSpec((1, H, G, D),
-                               lambda b, p, bt, ln, w: (b, 0, 0, 0)),
+        out_specs=pl.BlockSpec((1, H, G, D), lambda b, p, *_: (b, 0, 0, 0)),
         scratch_shapes=_scratch(H, G, D),
     )
     return pl.pallas_call(
@@ -217,4 +245,4 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
         # device trace (``%paged_gqa_decode_attention.N``), whatever
         # wrappers it is called through
         name="paged_gqa_decode_attention",
-    )(block_tables, kv_lens, window, q, k_pages, v_pages)
+    )(block_tables, kv_lens, window, first, last, q, k_pages, v_pages)

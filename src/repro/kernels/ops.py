@@ -72,7 +72,10 @@ def paged_gqa_decode_attention(q: jax.Array, k_pool: jax.Array,
     out (B,1,Hq,D).  The device-side read path of the serving KV pool
     (``repro.serving.kv_pool``): K/V are addressed *through* the block
     table, so batch membership and sequence length change without
-    recompilation or cache copies.
+    recompilation or cache copies.  The kernel reads and scores only
+    the pages that hold a lane's visible slots (``kv_len`` and the
+    window), so its work follows the live context, not ``max_pages``;
+    a lane with ``kv_len`` 0 scores nothing and returns 0.
 
     ``k_scale``/``v_scale`` ((n_pages*page_size, Hkv) f32) select the
     **int8 page** format (``--kv-dtype int8``): the pools hold int8

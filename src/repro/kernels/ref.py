@@ -46,8 +46,8 @@ def paged_decode_attention_ref(q: jax.Array, k_pages: jax.Array,
     shared physical page pool; block_tables (B,max_pages) maps each
     sequence's logical page j to a physical page id; kv_lens (B,) is the
     per-sequence token count (logical positions are contiguous 0..len-1,
-    unlike the ring cache).  Fully-masked rows (kv_len == 0, idle batch
-    slots) produce finite garbage, not NaN.
+    unlike the ring cache).  A row with no visible slot (kv_len == 0, an
+    idle batch slot) returns 0, as the Pallas kernel does.
 
     ``k_scales``/``v_scales`` (P, ps, H) switch on the **int8 page**
     format (``repro.quant.kv_int8``): pages hold int8 codes and the
@@ -79,6 +79,7 @@ def paged_decode_attention_ref(q: jax.Array, k_pages: jax.Array,
     valid &= (w <= 0) | (j[None, :] > qpos - w)
     s = jnp.where(valid[:, None, None, :], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
+    p = jnp.where(valid.any(axis=1)[:, None, None, None], p, 0.0)
     return jnp.einsum("bhgs,bshd->bhgd", p.astype(v.dtype), v,
                       preferred_element_type=jnp.float32)
 

@@ -81,7 +81,7 @@ STEP_SPANS: Dict[str, Tuple[str, ...]] = {
     SPAN_COW_COPY: (),
     SPAN_SYNC_TABLES: (),
     SPAN_PREFILL_CHUNK: ("start", "n", "fresh"),
-    SPAN_DECODE: ("lanes", "kv_tokens"),
+    SPAN_DECODE: ("lanes", "kv_tokens", "kv_pages"),
     SPAN_VERIFY: ("lanes", "fed"),
     SPAN_SAMPLE: (),
 }

@@ -522,14 +522,16 @@ class EngineCore:
                 # slots
                 sps = [plan.decodes[0].request.sampling] \
                     * self.max_running
-                kv_tokens = 0
+                kv_tokens = kv_pages = 0
                 for seq in plan.decodes:
                     pos[seq.slot] = seq.next_pos - 1  # fed-token position
                     fed[seq.slot, 0] = seq.generated[-1]
                     sps[seq.slot] = seq.request.sampling
                     kv_tokens += seq.next_pos
+                    kv_pages += -(-seq.next_pos // self.page_size)
                 with TraceAnnotation(SPAN_DECODE, lanes=len(plan.decodes),
-                                     kv_tokens=kv_tokens):
+                                     kv_tokens=kv_tokens,
+                                     kv_pages=kv_pages):
                     logits = self.runner.decode(fed, pos)
                 with TraceAnnotation(SPAN_SAMPLE):
                     toks = sample_grouped(logits, sps, self._next_key())

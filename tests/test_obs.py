@@ -406,6 +406,50 @@ def test_pool_gauges_sampled_per_step(tiny):
     assert sum(free.values()) == core.pool.n_free() - core.pool.n_retained()
 
 
+class _SpanRecorder:
+    """Stands in for ``TraceAnnotation``: records each span's name and
+    stats, those set after it opened included."""
+
+    def __init__(self, spans, name, **stats):
+        self.stats = dict(stats)
+        spans.append((name, self.stats))
+
+    def set_metadata(self, **stats):
+        self.stats.update(stats)
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+def test_decode_span_counts_the_pages_the_kernel_reads(tiny, monkeypatch):
+    """``serving.decode``'s ``kv_pages`` is the sum over live lanes of
+    ``cdiv(pos + 1, page_size)``, beside ``kv_tokens``, the sum of
+    ``pos + 1``; idle lanes count nothing."""
+    import functools
+    from repro.obs.trace import SPAN_DECODE, STEP_SPANS
+    from repro.serving import core as core_mod
+    spans = []
+    monkeypatch.setattr(core_mod, "TraceAnnotation",
+                        functools.partial(_SpanRecorder, spans))
+    clock = VirtualClock()
+    core = _fake_core(tiny, clock, max_len=64, max_running=3,
+                      page_size=8, prefix_cache=False)
+    # prompts of 10 and 7: the first decode feeds positions 10 and 7
+    # (11 and 8 tokens: 2 + 1 pages), the second 11 and 8 (12 and 9
+    # tokens: 2 + 2 pages); the third lane stays idle
+    for uid, plen in ((0, 10), (1, 7)):
+        core.submit(Request(uid=uid, prompt=list(range(1, plen + 1)),
+                            sampling=SamplingParams(max_new_tokens=3)))
+    _drain(core, clock)
+    decodes = [stats for name, stats in spans if name == SPAN_DECODE]
+    assert all(set(s) == set(STEP_SPANS[SPAN_DECODE]) for s in decodes)
+    assert [(s["lanes"], s["kv_tokens"], s["kv_pages"])
+            for s in decodes] == [(2, 19, 3), (2, 21, 4)]
+
+
 def test_null_registry_disables_engine_metrics(tiny):
     clock = VirtualClock()
     core = _fake_core(tiny, clock, registry=NullRegistry(), max_len=64,

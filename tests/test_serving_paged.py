@@ -277,6 +277,41 @@ class TestPagedKernel:
             np.testing.assert_allclose(np.asarray(ref), np.asarray(ker),
                                        rtol=1e-5, atol=1e-5)
 
+    @pytest.mark.parametrize("window", [0, 3, 6])
+    def test_walk_reads_only_visible_pages(self, window):
+        """Ragged lanes in one batch over a pool in which every page no
+        lane can see is NaN: unmapped pages, pages past a lane's last
+        slot and pages before its window.  A page the kernel scored
+        would poison ``p @ v`` even fully masked, so a finite output
+        equal to the oracle's (run with those pages zeroed) shows the
+        walk never touches them.  The idle lane (``kv_len`` 0) returns
+        0."""
+        from repro.kernels.decode_attention import paged_decode_attention
+        from repro.kernels.ref import paged_decode_attention_ref
+        Hkv, G, D, ps, mp = 2, 2, 8, 4, 4
+        lens = np.array([0, 1, ps, ps + 1, mp * ps], np.int32)
+        B, P = len(lens), 1 + len(lens) * mp
+        rng = np.random.default_rng(window)
+        bt = (1 + rng.permutation(B * mp)).reshape(B, mp).astype(np.int32)
+        q = jnp.asarray(rng.normal(size=(B, Hkv, G, D)), jnp.float32)
+        kp = rng.normal(size=(P, ps, Hkv, D)).astype(np.float32)
+        vp = rng.normal(size=(P, ps, Hkv, D)).astype(np.float32)
+        seen = np.zeros(P, bool)
+        for b, n in enumerate(lens):
+            lo = max(n - window, 0) if window else 0
+            seen[bt[b, lo // ps:(n + ps - 1) // ps]] = True
+        hidden = ~seen[:, None, None, None]
+        ker = np.asarray(paged_decode_attention(
+            q, jnp.asarray(np.where(hidden, np.nan, kp)),
+            jnp.asarray(np.where(hidden, np.nan, vp)), bt, lens, window,
+            interpret=True))
+        ref = np.asarray(paged_decode_attention_ref(
+            q, jnp.asarray(np.where(hidden, 0.0, kp)),
+            jnp.asarray(np.where(hidden, 0.0, vp)), bt, lens, window))
+        assert np.isfinite(ker).all()
+        np.testing.assert_allclose(ker, ref, rtol=1e-5, atol=1e-5)
+        np.testing.assert_array_equal(ker[0], 0.0)
+
 
 class TestScanEscapeLayout:
     """Per-layer paged-pool buffers outside the layer-scan carry.
