@@ -23,7 +23,6 @@ walks the H heads of a block with a static loop instead.
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
@@ -100,16 +99,16 @@ def _decode_attn_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
 
 
 def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
-                     kv_len, *, block_s: int = 512,
+                     kv_len, *, scale: float, block_s: int = 512,
                      interpret: bool = True) -> jax.Array:
-    """q (B,H,G,D) × cache k,v (B,S,H,D) -> out (B,H,G,D) f32."""
+    """q (B,H,G,D) × cache k,v (B,S,H,D) -> out (B,H,G,D) f32; scores
+    are ``q·k * scale``."""
     B, H, G, D = q.shape
     _, S, _, _ = k.shape
     block_s = min(block_s, S)
     if S % block_s:
         raise ValueError(f"S={S} not divisible by block_s={block_s}")
     n_s = S // block_s
-    scale = 1.0 / math.sqrt(D)
     kv_len = jnp.asarray(kv_len, jnp.int32).reshape(1)
 
     kernel = functools.partial(_decode_attn_kernel, block_s=block_s,
@@ -187,9 +186,10 @@ def _paged_decode_attn_kernel(bt_ref, len_ref, win_ref, first_ref, last_ref,
 def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
                            v_pages: jax.Array, block_tables: jax.Array,
                            kv_lens: jax.Array, window=0, *,
-                           softcap: float = 0.0,
+                           scale: float, softcap: float = 0.0,
                            interpret: bool = True) -> jax.Array:
-    """q (B,H,G,D) × page pool k,v (P,ps,H,D) -> out (B,H,G,D) f32.
+    """q (B,H,G,D) × page pool k,v (P,ps,H,D) -> out (B,H,G,D) f32;
+    scores are ``q·k * scale`` (before ``softcap``).
 
     ``k_pages``/``v_pages`` are the paged view of ONE layer's flat pool
     buffer — the ops wrapper (``repro.kernels.ops``) reshapes the
@@ -210,7 +210,6 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
     B, H, G, D = q.shape
     P, page_size, _, _ = k_pages.shape
     max_pages = block_tables.shape[1]
-    scale = 1.0 / math.sqrt(D)
     block_tables = jnp.asarray(block_tables, jnp.int32)
     kv_lens = jnp.asarray(kv_lens, jnp.int32).reshape(B)
     window = jnp.asarray(window, jnp.int32).reshape(1)

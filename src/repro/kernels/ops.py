@@ -8,7 +8,8 @@ back to the jnp oracle for speed (``impl="ref"``).
 from __future__ import annotations
 
 import functools
-from typing import Any
+import math
+from typing import Any, Optional
 
 import jax
 
@@ -35,32 +36,42 @@ def q4_matmul(x: jax.Array, packed: jax.Array, scales: jax.Array, *,
                            block_k=block_k, interpret=not _on_tpu())
 
 
-@functools.partial(jax.jit, static_argnames=("impl", "block_s"))
+def _score_scale(scale: Optional[float], D: int) -> float:
+    """The score scale a caller gave, or 1/sqrt(D) when it gave none."""
+    return 1.0 / math.sqrt(D) if scale is None else float(scale)
+
+
+@functools.partial(jax.jit, static_argnames=("impl", "block_s", "scale"))
 def gqa_decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                          kv_len: Any, *, impl: str = "auto",
-                         block_s: int = 512) -> jax.Array:
+                         block_s: int = 512,
+                         scale: Optional[float] = None) -> jax.Array:
     """Flash-decoding for one token with GQA.
 
     q (B,1,Hq,D); k,v (B,S,Hkv,D) -> out (B,1,Hq,D), matching the
-    model-zoo attention contract."""
+    model-zoo attention contract.  ``scale`` multiplies the scores
+    (None: 1/sqrt(D))."""
     B, one, Hq, D = q.shape
     Hkv = k.shape[2]
     G = Hq // Hkv
     qk = q.reshape(B, Hkv, G, D)
+    scale = _score_scale(scale, D)
     if impl == "ref" or (impl == "auto" and not _on_tpu()):
-        out = _ref.decode_attention_ref(qk, k, v, kv_len)
+        out = _ref.decode_attention_ref(qk, k, v, kv_len, scale=scale)
     else:
-        out = _decode_attention_kernel(qk, k, v, kv_len, block_s=block_s,
+        out = _decode_attention_kernel(qk, k, v, kv_len, scale=scale,
+                                       block_s=block_s,
                                        interpret=not _on_tpu())
     return out.reshape(B, 1, Hq, D)
 
 
 @functools.partial(jax.jit, static_argnames=("page_size", "impl",
-                                             "softcap"))
+                                             "softcap", "scale"))
 def paged_gqa_decode_attention(q: jax.Array, k_pool: jax.Array,
                                v_pool: jax.Array, block_tables: jax.Array,
                                kv_lens: jax.Array, window=0, *,
                                page_size: int, softcap: float = 0.0,
+                               scale: Optional[float] = None,
                                impl: str = "auto",
                                k_scale=None, v_scale=None) -> jax.Array:
     """Paged flash-decoding for one token per sequence with GQA.
@@ -75,7 +86,9 @@ def paged_gqa_decode_attention(q: jax.Array, k_pool: jax.Array,
     recompilation or cache copies.  The kernel reads and scores only
     the pages that hold a lane's visible slots (``kv_len`` and the
     window), so its work follows the live context, not ``max_pages``;
-    a lane with ``kv_len`` 0 scores nothing and returns 0.
+    a lane with ``kv_len`` 0 scores nothing and returns 0.  ``scale``
+    multiplies the scores (None: 1/sqrt(D); Granite's
+    ``attention_multiplier`` otherwise).
 
     ``k_scale``/``v_scale`` ((n_pages*page_size, Hkv) f32) select the
     **int8 page** format (``--kv-dtype int8``): the pools hold int8
@@ -99,21 +112,22 @@ def paged_gqa_decode_attention(q: jax.Array, k_pool: jax.Array,
     k_pages = k_pool.reshape(n_pages, page_size, Hkv, D)
     v_pages = v_pool.reshape(n_pages, page_size, Hkv, D)
     qk = q.reshape(B, Hkv, G, D)
+    scale = _score_scale(scale, D)
     if k_scale is not None or v_scale is not None:
         ks = k_scale.reshape(n_pages, page_size, Hkv)
         vs = v_scale.reshape(n_pages, page_size, Hkv)
         out = _ref.paged_decode_attention_ref(qk, k_pages, v_pages,
                                               block_tables, kv_lens, window,
-                                              softcap=softcap,
+                                              softcap=softcap, scale=scale,
                                               k_scales=ks, v_scales=vs)
     elif impl == "ref" or (impl == "auto" and not _on_tpu()):
         out = _ref.paged_decode_attention_ref(qk, k_pages, v_pages,
                                               block_tables, kv_lens, window,
-                                              softcap=softcap)
+                                              softcap=softcap, scale=scale)
     else:
         out = _paged_decode_attention_kernel(qk, k_pages, v_pages,
                                              block_tables, kv_lens, window,
-                                             softcap=softcap,
+                                             scale=scale, softcap=softcap,
                                              interpret=not _on_tpu())
     return out.reshape(B, 1, Hq, D).astype(q.dtype)
 

@@ -3,6 +3,7 @@
 from __future__ import annotations
 
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -18,11 +19,13 @@ def q4_gemm_ref(x: jax.Array, packed: jax.Array,
 
 
 def decode_attention_ref(q: jax.Array, k: jax.Array, v: jax.Array,
-                         kv_len) -> jax.Array:
-    """q (B,H,G,D) × cache k,v (B,S,H,D) -> (B,H,G,D) f32."""
+                         kv_len, *, scale: Optional[float] = None
+                         ) -> jax.Array:
+    """q (B,H,G,D) × cache k,v (B,S,H,D) -> (B,H,G,D) f32; scores
+    ``q·k * scale`` (None: 1/sqrt(D))."""
     B, H, G, D = q.shape
     S = k.shape[1]
-    scale = 1.0 / math.sqrt(D)
+    scale = 1.0 / math.sqrt(D) if scale is None else scale
     s = jnp.einsum("bhgd,bshd->bhgs", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
     mask = jnp.arange(S) < jnp.asarray(kv_len)
@@ -39,7 +42,8 @@ def paged_decode_attention_ref(q: jax.Array, k_pages: jax.Array,
                                v_pages: jax.Array, block_tables: jax.Array,
                                kv_lens: jax.Array,
                                window=0, softcap: float = 0.0,
-                               k_scales=None, v_scales=None) -> jax.Array:
+                               k_scales=None, v_scales=None, *,
+                               scale: Optional[float] = None) -> jax.Array:
     """Gather-based paged flash-decoding oracle.
 
     q (B,H,G,D) one token per sequence; k_pages/v_pages (P,ps,H,D) the
@@ -47,7 +51,8 @@ def paged_decode_attention_ref(q: jax.Array, k_pages: jax.Array,
     sequence's logical page j to a physical page id; kv_lens (B,) is the
     per-sequence token count (logical positions are contiguous 0..len-1,
     unlike the ring cache).  A row with no visible slot (kv_len == 0, an
-    idle batch slot) returns 0, as the Pallas kernel does.
+    idle batch slot) returns 0, as the Pallas kernel does.  Scores are
+    ``q·k * scale`` (None: 1/sqrt(D)).
 
     ``k_scales``/``v_scales`` (P, ps, H) switch on the **int8 page**
     format (``repro.quant.kv_int8``): pages hold int8 codes and the
@@ -58,7 +63,7 @@ def paged_decode_attention_ref(q: jax.Array, k_pages: jax.Array,
     P, ps, _, _ = k_pages.shape
     max_pages = block_tables.shape[1]
     L = max_pages * ps
-    scale = 1.0 / math.sqrt(D)
+    scale = 1.0 / math.sqrt(D) if scale is None else scale
     # gather each sequence's pages, flatten to its logical KV view
     k = k_pages[block_tables].reshape(B, L, H, D)
     v = v_pages[block_tables].reshape(B, L, H, D)

@@ -356,7 +356,8 @@ def make_decode_attn_hook(cfg: ModelConfig, mesh: Mesh, policy: Policy, *,
         part = flash_attention(
             q, ck, cv, causal=True, window=window, q_offset=pos,
             kv_positions=p_local, chunk=min(512, local),
-            return_partial=True, softcap=cfg.attn_logit_softcap)
+            return_partial=True, softcap=cfg.attn_logit_softcap,
+            scale=cfg.attention_multiplier)
         out = combine_partials(part, seq_axes, q.dtype)
         return out, ck, cv, cp
 

@@ -23,7 +23,7 @@ structure, so gemma3's 5-local:1-global pattern stays in uniform mode.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Tuple
+from typing import Any, Optional, Tuple
 
 import jax.numpy as jnp
 
@@ -51,6 +51,13 @@ class ModelConfig:
     sliding_window: int = GLOBAL_ATTENTION  # window for "local" layers
     local_global_pattern: Tuple[int, int] = (0, 0)  # (n_local, n_global) cycle
     attn_logit_softcap: float = 0.0
+    # Granite's muP scalars; each default is a plain block's value and
+    # emits no operation.  ``attention_multiplier`` None scores with
+    # 1/sqrt(head_dim).
+    attention_multiplier: Optional[float] = None
+    embedding_multiplier: float = 1.0   # embedding rows times this
+    residual_multiplier: float = 1.0    # each branch times this before its add
+    logits_scaling: float = 1.0         # logits divided by this
 
     # -- FFN / MoE -------------------------------------------------------
     act: str = "silu"              # silu | gelu

@@ -131,6 +131,14 @@ def _init_layer(key: jax.Array, cfg: ModelConfig, kind: str, *,
     return p
 
 
+def _times(x: jax.Array, m: float) -> jax.Array:
+    """``x * m`` computed in float32 and kept in ``x``'s dtype; ``m`` of 1
+    returns ``x`` itself, so a plain block's program is unchanged."""
+    if m == 1.0:
+        return x
+    return (x.astype(jnp.float32) * m).astype(x.dtype)
+
+
 # ----------------------------------------------------------------------
 # attention forward (shared by self/cross, train/prefill/decode)
 # ----------------------------------------------------------------------
@@ -275,6 +283,7 @@ def _paged_attn(cfg: ModelConfig, q: jax.Array, k: jax.Array, v: jax.Array,
                 q[:, s:s + 1], new_cache["k"], new_cache["v"],
                 paged["block_tables"], kv_len_s, window, page_size=ps,
                 softcap=cfg.attn_logit_softcap,
+                scale=cfg.attention_multiplier,
                 k_scale=new_cache.get("k_scale"),
                 v_scale=new_cache.get("v_scale")))
         out = jnp.concatenate(outs, axis=1)
@@ -300,17 +309,20 @@ def _paged_attn(cfg: ModelConfig, q: jax.Array, k: jax.Array, v: jax.Array,
             out = flash_attention(q, kctx, vctx, causal=True,
                                   window=window, q_offset=ctx["q_offset"],
                                   kv_len=ctx["kv_len"], chunk=ATTN_CHUNK,
-                                  softcap=cfg.attn_logit_softcap)
+                                  softcap=cfg.attn_logit_softcap,
+                                  scale=cfg.attention_multiplier)
         else:
             out = flash_attention(q, k, v, causal=True, window=window,
                                   chunk=ATTN_CHUNK,
-                                  softcap=cfg.attn_logit_softcap)
+                                  softcap=cfg.attn_logit_softcap,
+                                  scale=cfg.attention_multiplier)
     else:                                     # decode: one token per slot
         write(k[:, 0], v[:, 0])
         out = paged_gqa_decode_attention(
             q, new_cache["k"], new_cache["v"], paged["block_tables"],
             paged["kv_len"], window, page_size=ps,
             softcap=cfg.attn_logit_softcap,
+            scale=cfg.attention_multiplier,
             k_scale=new_cache.get("k_scale"),
             v_scale=new_cache.get("v_scale"))
     return out, new_cache
@@ -358,12 +370,14 @@ def _self_attn(cfg: ModelConfig, ap: Params, x: jax.Array,
             # and independent of the cache's storage sharding
             out = flash_attention(
                 q, k, v, causal=True, window=window, chunk=ATTN_CHUNK,
-                softcap=cfg.attn_logit_softcap)
+                softcap=cfg.attn_logit_softcap,
+                scale=cfg.attention_multiplier)
         else:
             out = flash_attention(
                 q, ck, cv, causal=True, window=window,
                 q_offset=positions[0], kv_positions=cp, chunk=ATTN_CHUNK,
-                softcap=cfg.attn_logit_softcap)
+                softcap=cfg.attn_logit_softcap,
+                scale=cfg.attention_multiplier)
     else:
         # training path: rematerialise the blockwise attention in the
         # backward pass — the kv-chunk scan would otherwise save its
@@ -372,7 +386,8 @@ def _self_attn(cfg: ModelConfig, ap: Params, x: jax.Array,
         def attn_fn(q_, k_, v_, w_):
             return flash_attention(
                 q_, k_, v_, causal=causal, window=w_, chunk=ATTN_CHUNK,
-                softcap=cfg.attn_logit_softcap)
+                softcap=cfg.attn_logit_softcap,
+                scale=cfg.attention_multiplier)
         out = jax.checkpoint(attn_fn)(q, k, v, window)
     if act_constraint is not None:
         out = act_constraint(out)
@@ -400,7 +415,8 @@ def _cross_attn(cfg: ModelConfig, ap: Params, x: jax.Array,
     if cfg.qk_norm:
         q = rms_norm(q, ap["q_norm"], cfg.norm_eps)
         k = rms_norm(k, ap["k_norm"], cfg.norm_eps)
-    out = flash_attention(q, k, v, causal=False, chunk=ATTN_CHUNK)
+    out = flash_attention(q, k, v, causal=False, chunk=ATTN_CHUNK,
+                          scale=cfg.attention_multiplier)
     out = out.reshape(B, S, -1) @ ap["w_o"]
     if "gate" in ap:
         out = jnp.tanh(ap["gate"].astype(jnp.float32)).astype(out.dtype) * out
@@ -439,7 +455,8 @@ def _layer_forward(cfg: ModelConfig, kind: str, lp: Params, x: jax.Array,
                            qmm=qmm)
         # post-Gather activations are remat save-points: recomputing
         # them would repeat the TP psum in the backward pass
-        x = x + checkpoint_name(a, "block_out")
+        x = x + _times(checkpoint_name(a, "block_out"),
+                       cfg.residual_multiplier)
         new_cache = {} if cache is not None else None
         if kv is not None:
             assert new_cache is not None
@@ -450,7 +467,8 @@ def _layer_forward(cfg: ModelConfig, kind: str, lp: Params, x: jax.Array,
             x = x + _cross_attn(cfg, lp["xattn"], hx, memory)
         h2 = _apply_norm(cfg, lp["ln2"], x)
         f, aux = _ffn(cfg, lp, h2, moe_hook, qmm)
-        x = x + checkpoint_name(f, "block_out")
+        x = x + _times(checkpoint_name(f, "block_out"),
+                       cfg.residual_multiplier)
     elif kind == "xattn":
         assert memory is not None
         x = x + _cross_attn(cfg, lp["xattn"], h, memory)
@@ -506,6 +524,10 @@ class Model:
                               if self.block_period else 0)
         self.n_tail = (cfg.n_layers - self.n_full_blocks * self.block_period
                        if self.block_period else cfg.n_layers)
+        if cfg.residual_multiplier != 1.0 and set(self.kinds) != {"attn"}:
+            raise NotImplementedError(
+                f"arch {cfg.name!r}: residual_multiplier scales the "
+                "branches of self-attention blocks only")
         #: optional sharding hooks installed by the launcher
         #: (repro.launch.shardings): per-layer weight unshard constraint
         #: (FSDP) and activation batch constraint.
@@ -975,11 +997,24 @@ class Model:
             return batch["image_embeds"]
         return None
 
+    def _embed(self, params: Params, tokens: jax.Array) -> jax.Array:
+        """Embedding rows of ``tokens``, times ``embedding_multiplier``."""
+        x = jnp.take(params["embed"], tokens, axis=0)
+        return _times(x, self.cfg.embedding_multiplier)
+
+    def _unembed(self, params: Params, h: jax.Array) -> jax.Array:
+        """Float32 logits of normed hidden states ``h``, divided by
+        ``logits_scaling``."""
+        cfg = self.cfg
+        head = params["embed"] if cfg.tie_embeddings else params["lm_head"]
+        logits = unembed(head, h, tied=cfg.tie_embeddings)
+        if cfg.logits_scaling != 1.0:
+            logits = logits / cfg.logits_scaling
+        return logits
+
     def _logits(self, params: Params, x: jax.Array) -> jax.Array:
-        x = _apply_norm(self.cfg, params["final_norm"], x)
-        head = (params["embed"] if self.cfg.tie_embeddings
-                else params["lm_head"])
-        return unembed(head, x, tied=self.cfg.tie_embeddings)
+        return self._unembed(params,
+                             _apply_norm(self.cfg, params["final_norm"], x))
 
     # ------------------------------------------------------------------
     # public entry points
@@ -987,7 +1022,7 @@ class Model:
     def _hidden_states(self, params: Params, batch: Dict[str, Any],
                        ) -> Tuple[jax.Array, jax.Array]:
         tokens = batch["tokens"]
-        x = jnp.take(params["embed"], tokens, axis=0)
+        x = self._embed(params, tokens)
         if self.act_constraint is not None:
             x = self.act_constraint(x)
         positions = jnp.arange(tokens.shape[1])
@@ -1000,9 +1035,7 @@ class Model:
                 ) -> Tuple[jax.Array, jax.Array]:
         """Teacher-forcing logits over the whole sequence (train)."""
         h, aux = self._hidden_states(params, batch)
-        head = (params["embed"] if self.cfg.tie_embeddings
-                else params["lm_head"])
-        return unembed(head, h, tied=self.cfg.tie_embeddings), aux
+        return self._unembed(params, h), aux
 
     LOSS_CHUNK = 512
 
@@ -1012,8 +1045,6 @@ class Model:
         sequence chunk at a time — full (B, S, V) fp32 logits of a
         256k-vocab model would dwarf every other activation."""
         h, aux = self._hidden_states(params, batch)
-        head = (params["embed"] if self.cfg.tie_embeddings
-                else params["lm_head"])
         labels = batch["labels"]
         B, S, d = h.shape
         C = min(self.LOSS_CHUNK, S)
@@ -1031,8 +1062,7 @@ class Model:
             h_i, y_i = xs
             if self.act_constraint is not None:
                 h_i = self.act_constraint(h_i)
-            logits = unembed(head, h_i, tied=self.cfg.tie_embeddings)
-            lf = logits.astype(jnp.float32)
+            lf = self._unembed(params, h_i)
             logz = jax.nn.logsumexp(lf, axis=-1)
             gold = jnp.take_along_axis(
                 lf, jnp.clip(y_i, 0)[..., None], axis=-1)[..., 0]
@@ -1054,7 +1084,7 @@ class Model:
                 ) -> Tuple[jax.Array, Dict[str, Any]]:
         """Process the prompt, fill the cache, return last-token logits."""
         tokens = batch["tokens"]
-        x = jnp.take(params["embed"], tokens, axis=0)
+        x = self._embed(params, tokens)
         if self.act_constraint is not None:
             x = self.act_constraint(x)
         positions = jnp.arange(tokens.shape[1])
@@ -1102,7 +1132,7 @@ class Model:
         Sp = tokens.shape[1]
         slot = jnp.asarray(slot, jnp.int32)
         plen = jnp.asarray(plen, jnp.int32)
-        x = jnp.take(params["embed"], tokens, axis=0)
+        x = self._embed(params, tokens)
         offsets = jnp.arange(Sp)
         bt_row = cache["block_tables"][slot]              # (max_pages,)
         if start is None:
@@ -1157,7 +1187,7 @@ class Model:
             return self._decode_step_paged(
                 params, cache, tokens, pos, page_size=page_size,
                 window_override=window_override)
-        x = jnp.take(params["embed"], tokens, axis=0)
+        x = self._embed(params, tokens)
         if self.cache_constraint is not None:
             cache = self.cache_constraint(cache)
         positions = pos + jnp.arange(1)
@@ -1179,7 +1209,7 @@ class Model:
                            ) -> Tuple[jax.Array, Dict[str, Any]]:
         pos = jnp.asarray(pos, jnp.int32)                 # (B,)
         safe_pos = jnp.maximum(pos, 0)
-        x = jnp.take(params["embed"], tokens, axis=0)     # (B, 1, d)
+        x = self._embed(params, tokens)                   # (B, 1, d)
         bt = cache["block_tables"]
         B = bt.shape[0]
         phys = bt[jnp.arange(B), safe_pos // page_size] * page_size \
@@ -1229,7 +1259,7 @@ class Model:
         pos = jnp.asarray(pos, jnp.int32)                 # (B,)
         n_fed = jnp.asarray(n_fed, jnp.int32)             # (B,)
         safe_pos = jnp.maximum(pos, 0)
-        x = jnp.take(params["embed"], tokens, axis=0)     # (B, S, d)
+        x = self._embed(params, tokens)                   # (B, S, d)
         bt = cache["block_tables"]
         B, S = tokens.shape
         offs = jnp.arange(S, dtype=jnp.int32)[None, :]    # (1, S)

@@ -68,8 +68,8 @@ class TestDecodeAttention:
         k = _rand((B, S, H, D), 1)
         v = _rand((B, S, H, D), 2)
         out = decode_attention(jnp.asarray(q), jnp.asarray(k),
-                               jnp.asarray(v), kv_len, block_s=bs,
-                               interpret=True)
+                               jnp.asarray(v), kv_len, scale=D ** -0.5,
+                               block_s=bs, interpret=True)
         want = ref.decode_attention_ref(jnp.asarray(q), jnp.asarray(k),
                                         jnp.asarray(v), kv_len)
         np.testing.assert_allclose(np.asarray(out), np.asarray(want),

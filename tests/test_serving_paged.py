@@ -273,7 +273,7 @@ class TestPagedKernel:
                                              lens, window)
             ker = paged_decode_attention(jnp.asarray(q), jnp.asarray(kp),
                                          jnp.asarray(vp), bt, lens, window,
-                                         interpret=True)
+                                         scale=D ** -0.5, interpret=True)
             np.testing.assert_allclose(np.asarray(ref), np.asarray(ker),
                                        rtol=1e-5, atol=1e-5)
 
@@ -304,7 +304,7 @@ class TestPagedKernel:
         ker = np.asarray(paged_decode_attention(
             q, jnp.asarray(np.where(hidden, np.nan, kp)),
             jnp.asarray(np.where(hidden, np.nan, vp)), bt, lens, window,
-            interpret=True))
+            scale=D ** -0.5, interpret=True))
         ref = np.asarray(paged_decode_attention_ref(
             q, jnp.asarray(np.where(hidden, 0.0, kp)),
             jnp.asarray(np.where(hidden, 0.0, vp)), bt, lens, window))

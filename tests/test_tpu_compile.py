@@ -53,15 +53,20 @@ def _compiled_text(fn, sharding, *shapes) -> str:
 
 
 # qwen3-1.7b decode: 8 slots, 8 kv heads (2 per chip under 4-way TP),
-# GQA group 2, head_dim 128, 16-token pages, max_len 1024
-@pytest.mark.parametrize("hkv", [8, 2], ids=["hkv8", "hkv2_tp_local"])
-def test_paged_decode_attention_compiles(one_chip, hkv):
+# GQA group 2, head_dim 128, 16-token pages, max_len 1024; the
+# granite-3-8b pipeline stage: 32 lanes x 96 pages (max_len 1536), 32
+# query / 8 kv heads, scores times Granite's attention_multiplier 1/128
+@pytest.mark.parametrize("B,hkv,G,max_pages,scale", [
+    (8, 8, 2, 64, 128 ** -0.5), (8, 2, 2, 64, 128 ** -0.5),
+    (32, 8, 4, 96, 1 / 128)], ids=["hkv8", "hkv2_tp_local", "granite_stage"])
+def test_paged_decode_attention_compiles(one_chip, B, hkv, G, max_pages,
+                                         scale):
     from repro.kernels.decode_attention import paged_decode_attention
-    B, G, D, ps, max_pages = 8, 2, 128, 16, 64
+    D, ps = 128, 16
     n_pages = 1 + B * max_pages
 
     def fn(q, kp, vp, bt, lens):
-        return paged_decode_attention(q, kp, vp, bt, lens, 0,
+        return paged_decode_attention(q, kp, vp, bt, lens, 0, scale=scale,
                                       interpret=False)
 
     text = _compiled_text(
@@ -83,7 +88,7 @@ def _readers_kernel_patterns():
     metrics = Path(__file__).resolve().parents[1] / "bench" / "metrics"
     out = {}
     for name in ("decode_step_ms", "paged_attn_roofline",
-                 "prefill_ms_per_ktok"):
+                 "prefill_ms_per_ktok", "decode_hbm_roofline"):
         src = (metrics / f"{name}.py").read_text().splitlines()
         line = next(ln for ln in src if ln.startswith("KERNEL = "))
         out[name] = ast.literal_eval(line.split("=", 1)[1].strip())
@@ -103,7 +108,7 @@ def test_paged_kernel_instruction_name(one_chip, via):
 
     def kernel(q, kp, vp, bt, lens):
         return paged_decode_attention(q, kp, vp, bt, lens, 0,
-                                      interpret=False)
+                                      scale=D ** -0.5, interpret=False)
 
     wrapped = jax.jit(kernel)
 

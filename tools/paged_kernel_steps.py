@@ -84,7 +84,7 @@ def main(argv=None) -> int:
     bt = jnp.asarray((1 + rng.permutation(B * MAX_PAGES))
                      .reshape(B, MAX_PAGES), jnp.int32)
     fn = jax.jit(lambda q, kp, vp, bt, lens: paged_decode_attention(
-        q, kp, vp, bt, lens, 0, interpret=False))
+        q, kp, vp, bt, lens, 0, scale=D ** -0.5, interpret=False))
 
     rows, outs = {}, {}
     for name, lens in mixes(rng).items():
