@@ -76,19 +76,19 @@ def paged_gqa_decode_attention(q: jax.Array, k_pool: jax.Array,
                                k_scale=None, v_scale=None) -> jax.Array:
     """Paged flash-decoding for one token per sequence with GQA.
 
-    q (B,1,Hq,D); k_pool,v_pool (n_pages*page_size,Hkv,D) — ONE layer's
+    q (B,1,Hq,D); k_pool,v_pool (n_pages*page_size,Hkv*D) — ONE layer's
     flat page-pool buffer, exactly as the per-layer paged cache holds it
-    (``Model.init_cache(page_size=...)``); the paged view is a free
-    reshape here.  block_tables (B,max_pages); kv_lens (B,) ->
-    out (B,1,Hq,D).  The device-side read path of the serving KV pool
-    (``repro.serving.kv_pool``): K/V are addressed *through* the block
-    table, so batch membership and sequence length change without
-    recompilation or cache copies.  The kernel reads and scores only
-    the pages that hold a lane's visible slots (``kv_len`` and the
-    window), so its work follows the live context, not ``max_pages``;
-    a lane with ``kv_len`` 0 scores nothing and returns 0.  ``scale``
-    multiplies the scores (None: 1/sqrt(D); Granite's
-    ``attention_multiplier`` otherwise).
+    (``Model.init_cache(page_size=...)``); the paged view
+    (n_pages, page_size, Hkv*D) is a free reshape here.  block_tables
+    (B,max_pages); kv_lens (B,) -> out (B,1,Hq,D).  The device-side
+    read path of the serving KV pool (``repro.serving.kv_pool``): K/V
+    are addressed *through* the block table, so batch membership and
+    sequence length change without recompilation or cache copies.
+    The kernel reads and scores only the pages that hold a lane's
+    visible slots (``kv_len`` and the window), so its work follows the
+    live context, not ``max_pages``; a lane with ``kv_len`` 0 scores
+    nothing and returns 0.  ``scale`` multiplies the scores (None:
+    1/sqrt(D); Granite's ``attention_multiplier`` otherwise).
 
     ``k_scale``/``v_scale`` ((n_pages*page_size, Hkv) f32) select the
     **int8 page** format (``--kv-dtype int8``): the pools hold int8
@@ -99,7 +99,7 @@ def paged_gqa_decode_attention(q: jax.Array, k_pool: jax.Array,
     (``docs/quantization.md``).
     """
     B, one, Hq, D = q.shape
-    Hkv = k_pool.shape[1]
+    Hkv = k_pool.shape[1] // D
     G = Hq // Hkv
     n_pages = k_pool.shape[0] // page_size
     # all shapes here may be the TP-local slice: under the head-sharded
@@ -109,8 +109,8 @@ def paged_gqa_decode_attention(q: jax.Array, k_pool: jax.Array,
     # is identical, the GQA group size G is shard-invariant, and no
     # collective appears at this level (the head merge happens in the
     # transformer, once per layer)
-    k_pages = k_pool.reshape(n_pages, page_size, Hkv, D)
-    v_pages = v_pool.reshape(n_pages, page_size, Hkv, D)
+    k_pages = k_pool.reshape(n_pages, page_size, Hkv * D)
+    v_pages = v_pool.reshape(n_pages, page_size, Hkv * D)
     qk = q.reshape(B, Hkv, G, D)
     scale = _score_scale(scale, D)
     if k_scale is not None or v_scale is not None:

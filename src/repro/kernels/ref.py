@@ -46,13 +46,14 @@ def paged_decode_attention_ref(q: jax.Array, k_pages: jax.Array,
                                scale: Optional[float] = None) -> jax.Array:
     """Gather-based paged flash-decoding oracle.
 
-    q (B,H,G,D) one token per sequence; k_pages/v_pages (P,ps,H,D) the
-    shared physical page pool; block_tables (B,max_pages) maps each
-    sequence's logical page j to a physical page id; kv_lens (B,) is the
-    per-sequence token count (logical positions are contiguous 0..len-1,
-    unlike the ring cache).  A row with no visible slot (kv_len == 0, an
-    idle batch slot) returns 0, as the Pallas kernel does.  Scores are
-    ``q·k * scale`` (None: 1/sqrt(D)).
+    q (B,H,G,D) one token per sequence; k_pages/v_pages (P,ps,H,D) or
+    (P,ps,H*D), the shared physical page pool; block_tables
+    (B,max_pages) maps each sequence's logical page j to a physical
+    page id; kv_lens (B,) is the per-sequence token count (logical
+    positions are contiguous 0..len-1, unlike the ring cache).  A row
+    with no visible slot (kv_len == 0, an idle batch slot) returns 0,
+    as the Pallas kernel does.  Scores are ``q·k * scale`` (None:
+    1/sqrt(D)).
 
     ``k_scales``/``v_scales`` (P, ps, H) switch on the **int8 page**
     format (``repro.quant.kv_int8``): pages hold int8 codes and the
@@ -60,7 +61,7 @@ def paged_decode_attention_ref(q: jax.Array, k_pages: jax.Array,
     so dequantization costs O(gathered bytes), never O(pool bytes).
     """
     B, H, G, D = q.shape
-    P, ps, _, _ = k_pages.shape
+    ps = k_pages.shape[1]
     max_pages = block_tables.shape[1]
     L = max_pages * ps
     scale = 1.0 / math.sqrt(D) if scale is None else scale

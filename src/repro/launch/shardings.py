@@ -530,11 +530,12 @@ def serving_tp_param_specs(params_shapes: Any, *, axis: str = "model",
 def paged_cache_specs(cache_shapes: Any, *, axis: str = "model") -> Any:
     """PartitionSpec tree for the paged device cache under TP.
 
-    Each per-layer flat pool buffer (rows, Hkv, D) shards its **kv-head
-    dim** over ``axis`` — every shard holds its head slice of every
-    page, so page allocation, sharing, CoW and eviction stay pure host
-    bookkeeping with zero cross-shard byte traffic.  Block tables (and
-    anything else host-written) replicate.
+    Each per-layer flat pool buffer (rows, Hkv * D) shards its **kv-head
+    columns** over ``axis`` — contiguous blocks of whole heads, so every
+    shard holds its head slice of every page, and page allocation,
+    sharing, CoW and eviction stay pure host bookkeeping with zero
+    cross-shard byte traffic.  Block tables (and anything else
+    host-written) replicate.
 
     Int8 pools (``--kv-dtype int8``) add ``k_scale``/``v_scale``
     buffers (rows, Hkv) whose head dim shards exactly like the code
@@ -543,9 +544,7 @@ def paged_cache_specs(cache_shapes: Any, *, axis: str = "model") -> Any:
     """
     def f(path, leaf):
         name = _path_str(path).split("/")[-1]
-        if name in ("k", "v") and leaf.ndim == 3:
-            return P(None, axis, None)
-        if name in ("k_scale", "v_scale") and leaf.ndim == 2:
+        if name in ("k", "v", "k_scale", "v_scale") and leaf.ndim == 2:
             return P(None, axis)
         return P()
     return jax.tree_util.tree_map_with_path(f, cache_shapes)

@@ -221,7 +221,7 @@ def _paged_attn(cfg: ModelConfig, q: jax.Array, k: jax.Array, v: jax.Array,
     """Slot-mapped cache write + block-table attention read.
 
     ``cache['k']/['v']`` are THIS layer's flat page-pool buffers
-    ((n_pages * page_size, Hkv, D)); ``paged`` carries the per-call slot
+    ((n_pages * page_size, Hkv * D)); ``paged`` carries the per-call slot
     mapping (see ``Model.init_cache`` docstring).  Prefill (S > 1)
     scatters the fresh K/V rows to their physical slots; a one-shot
     prefill of a fresh sequence attends over the fresh K/V directly
@@ -251,15 +251,19 @@ def _paged_attn(cfg: ModelConfig, q: jax.Array, k: jax.Array, v: jax.Array,
     new_cache: Dict[str, jax.Array] = {}
 
     def write(rows_k, rows_v):
-        """Scatter this call's fresh rows ((n, Hkv, D) at write_slots)."""
+        """Scatter this call's fresh rows ((n, Hkv, D) at write_slots)
+        into the pool's (rows, Hkv * D) layout."""
+        n = rows_k.shape[0]
         if not quantized:
-            new_cache["k"] = cache["k"].at[write_slots].set(rows_k)
-            new_cache["v"] = cache["v"].at[write_slots].set(rows_v)
+            new_cache["k"] = cache["k"].at[write_slots].set(
+                rows_k.reshape(n, -1))
+            new_cache["v"] = cache["v"].at[write_slots].set(
+                rows_v.reshape(n, -1))
             return
         qk_, sk_ = quantize_rows(rows_k)
         qv_, sv_ = quantize_rows(rows_v)
-        new_cache["k"] = cache["k"].at[write_slots].set(qk_)
-        new_cache["v"] = cache["v"].at[write_slots].set(qv_)
+        new_cache["k"] = cache["k"].at[write_slots].set(qk_.reshape(n, -1))
+        new_cache["v"] = cache["v"].at[write_slots].set(qv_.reshape(n, -1))
         new_cache["k_scale"] = cache["k_scale"].at[write_slots].set(sk_)
         new_cache["v_scale"] = cache["v_scale"].at[write_slots].set(sv_)
 
@@ -296,16 +300,17 @@ def _paged_attn(cfg: ModelConfig, q: jax.Array, k: jax.Array, v: jax.Array,
             # the pool (written by prior chunks, shared prefix pages, or
             # a copy-on-write clone) — gather them *after* this chunk's
             # write so q sees [0, kv_len) at absolute positions
+            Hkv, D = k.shape[2], k.shape[3]
+            kctx = ck[ctx["phys"]].reshape(-1, Hkv, D)
+            vctx = cv[ctx["phys"]].reshape(-1, Hkv, D)
             if quantized:
-                kctx = dequantize_rows(ck[ctx["phys"]],
+                kctx = dequantize_rows(kctx,
                                        new_cache["k_scale"][ctx["phys"]],
-                                       q.dtype)[None]
-                vctx = dequantize_rows(cv[ctx["phys"]],
+                                       q.dtype)
+                vctx = dequantize_rows(vctx,
                                        new_cache["v_scale"][ctx["phys"]],
-                                       q.dtype)[None]
-            else:
-                kctx = ck[ctx["phys"]][None]
-                vctx = cv[ctx["phys"]][None]
+                                       q.dtype)
+            kctx, vctx = kctx[None], vctx[None]
             out = flash_attention(q, kctx, vctx, causal=True,
                                   window=window, q_offset=ctx["q_offset"],
                                   kv_len=ctx["kv_len"], chunk=ATTN_CHUNK,
@@ -643,9 +648,14 @@ class Model:
         physical pool of ``n_pages`` fixed-size pages per layer and are
         addressed through it —
 
-        * ``layers[i].self.k/v``  (n_pages * page_size, Hkv, D) flat
+        * ``layers[i].self.k/v``  (n_pages * page_size, Hkv * D) flat
           page-pool buffer of layer ``i`` (page 0 is reserved scratch:
-          idle batch slots and padded prefill positions write there).
+          idle batch slots and padded prefill positions write there),
+          one row per token with its kv heads side by side: on a TPU a
+          ``(rows, Hkv, D)`` buffer tiles one token's heads together, so
+          reading one head's rows takes a strided pick, while these rows
+          give the paged decode kernel each head as lane-aligned
+          columns of a page, with no relayout of the pool.
           The layers are a **Python list of independent buffers**, not
           one stacked (L, ...) array: each buffer is its own jit
           argument/result, so the compiled step never threads the pool
@@ -692,9 +702,9 @@ class Model:
             def layer():
                 if kv_dtype == "int8":
                     return {"self": {
-                        "k": jnp.zeros((rows, cfg.n_kv_heads, hd),
+                        "k": jnp.zeros((rows, cfg.n_kv_heads * hd),
                                        jnp.int8),
-                        "v": jnp.zeros((rows, cfg.n_kv_heads, hd),
+                        "v": jnp.zeros((rows, cfg.n_kv_heads * hd),
                                        jnp.int8),
                         "k_scale": jnp.zeros((rows, cfg.n_kv_heads),
                                              jnp.float32),
@@ -704,8 +714,8 @@ class Model:
                     raise ValueError(f"kv_dtype={kv_dtype!r}: "
                                      "choose 'fp32' or 'int8'")
                 return {"self": {
-                    "k": jnp.zeros((rows, cfg.n_kv_heads, hd), cfg.dtype),
-                    "v": jnp.zeros((rows, cfg.n_kv_heads, hd), cfg.dtype)}}
+                    "k": jnp.zeros((rows, cfg.n_kv_heads * hd), cfg.dtype),
+                    "v": jnp.zeros((rows, cfg.n_kv_heads * hd), cfg.dtype)}}
 
             return {
                 "block_tables": jnp.zeros((batch, max_pages), jnp.int32),

@@ -1,11 +1,11 @@
 """int8 KV-page quantization for the paged serving cache.
 
 The paged pool stores each layer's K/V as a flat row buffer
-((n_pages * page_size, Hkv, D) — see ``Model.init_cache``).  Under
+((n_pages * page_size, Hkv * D) — see ``Model.init_cache``).  Under
 ``kv_dtype="int8"`` the same rows hold int8 codes plus one f32 scale
 per **(row, kv head)**:
 
-    k / v           (rows, Hkv, D) int8   code = round(x / scale)
+    k / v           (rows, Hkv * D) int8  code = round(x / scale)
     k_scale/v_scale (rows, Hkv)    f32    scale = max|x| / 127
 
 Per-(token, head) scales — not per-page — because pages fill one token

@@ -33,6 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
 
+from ..kernels.decode_attention import pages_per_block
 from ..models.transformer import Model
 from ..obs.metrics import MetricsRegistry, NullRegistry
 from ..obs.trace import (SPAN_COW_COPY, SPAN_DECODE, SPAN_PREFILL_CHUNK,
@@ -522,16 +523,20 @@ class EngineCore:
                 # slots
                 sps = [plan.decodes[0].request.sampling] \
                     * self.max_running
-                kv_tokens = kv_pages = 0
+                ppb = pages_per_block(self.page_size, self.runner.max_pages)
+                kv_tokens = kv_pages = kv_blocks = 0
                 for seq in plan.decodes:
                     pos[seq.slot] = seq.next_pos - 1  # fed-token position
                     fed[seq.slot, 0] = seq.generated[-1]
                     sps[seq.slot] = seq.request.sampling
                     kv_tokens += seq.next_pos
-                    kv_pages += -(-seq.next_pos // self.page_size)
+                    pages = -(-seq.next_pos // self.page_size)
+                    kv_pages += pages
+                    kv_blocks += -(-pages // ppb)
                 with TraceAnnotation(SPAN_DECODE, lanes=len(plan.decodes),
                                      kv_tokens=kv_tokens,
-                                     kv_pages=kv_pages):
+                                     kv_pages=kv_pages,
+                                     kv_blocks=kv_blocks):
                     logits = self.runner.decode(fed, pos)
                 with TraceAnnotation(SPAN_SAMPLE):
                     toks = sample_grouped(logits, sps, self._next_key())

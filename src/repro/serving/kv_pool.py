@@ -633,7 +633,7 @@ class KVCachePool:
         index vectors for ONE per-layer pool buffer.
 
         The device cache holds each layer's pool as an independent
-        ``(n_pages * page_size, H, D)`` buffer (the scan-escape layout),
+        ``(n_pages * page_size, H * D)`` buffer (the scan-escape layout),
         so a page copy is the same row-index gather+scatter on every
         layer's buffer — one plan serves all layers.  ``pad_to_pages``
         pads the plan with scratch-page self-copies (row ``0 -> 0`` is a

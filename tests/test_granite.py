@@ -244,8 +244,9 @@ def test_paged_kernel_scale_matches_oracle(scale):
     q = jnp.asarray(4 * rng.normal(size=(B, Hkv, G, D)), jnp.float32)
     kp = jnp.asarray(rng.normal(size=(P, ps, Hkv, D)), jnp.float32)
     vp = jnp.asarray(rng.normal(size=(P, ps, Hkv, D)), jnp.float32)
-    ker = paged_decode_attention(q, kp, vp, bt, lens, 0, scale=s,
-                                 interpret=True)
+    ker = paged_decode_attention(q, kp.reshape(P, ps, Hkv * D),
+                                 vp.reshape(P, ps, Hkv * D), bt, lens, 0,
+                                 scale=s, interpret=True)
     ref = paged_decode_attention_ref(q, kp, vp, bt, lens, 0, scale=s)
     # float32 throughout; online softmax against one softmax
     np.testing.assert_allclose(np.asarray(ker), np.asarray(ref),

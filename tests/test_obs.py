@@ -446,8 +446,46 @@ def test_decode_span_counts_the_pages_the_kernel_reads(tiny, monkeypatch):
     _drain(core, clock)
     decodes = [stats for name, stats in spans if name == SPAN_DECODE]
     assert all(set(s) == set(STEP_SPANS[SPAN_DECODE]) for s in decodes)
-    assert [(s["lanes"], s["kv_tokens"], s["kv_pages"])
-            for s in decodes] == [(2, 19, 3), (2, 21, 4)]
+    # a block of the kernel's walk holds every page of a 64-token table
+    assert [(s["lanes"], s["kv_tokens"], s["kv_pages"], s["kv_blocks"])
+            for s in decodes] == [(2, 19, 3, 2), (2, 21, 4, 2)]
+
+
+def test_decode_span_counts_the_blocks_the_kernel_fetches(tiny,
+                                                          monkeypatch):
+    """``serving.decode``'s ``kv_blocks`` is the sum over live lanes of
+    ``cdiv(visible pages, pages_per_block)``: the blocks the paged
+    kernel's walk fetches, in a ragged batch whose long lane spans
+    several blocks."""
+    import functools
+    from repro.kernels.decode_attention import pages_per_block
+    from repro.obs.trace import SPAN_DECODE
+    from repro.serving import core as core_mod
+    spans = []
+    monkeypatch.setattr(core_mod, "TraceAnnotation",
+                        functools.partial(_SpanRecorder, spans))
+    clock = VirtualClock()
+    core = _fake_core(tiny, clock, max_len=512, max_running=4,
+                      page_size=8, prefix_cache=False)
+    ppb = pages_per_block(8, core.runner.max_pages)
+    blk = ppb * 8                       # tokens a block holds
+    assert 4 * blk <= 512               # several blocks in a table
+    plens = (7, blk, 2 * blk - 1, 2 * blk + 44)
+    for uid, plen in enumerate(plens):
+        core.submit(Request(uid=uid, prompt=list(range(1, plen + 1)),
+                            sampling=SamplingParams(max_new_tokens=3)))
+    _drain(core, clock)
+    decodes = [stats for name, stats in spans if name == SPAN_DECODE]
+    # decode i feeds position plen + i: plen + i + 1 tokens are visible;
+    # the third lane fills its second block exactly at the first decode
+    # and opens a third at the second
+    def cdiv(a, b):
+        return -(-a // b)
+    want = [sum(cdiv(cdiv(n + i + 1, 8), ppb) for n in plens)
+            for i in range(2)]
+    assert want == [1 + 2 + 2 + 3, 1 + 2 + 3 + 3]
+    assert [s["lanes"] for s in decodes] == [4, 4]
+    assert [s["kv_blocks"] for s in decodes] == want
 
 
 def test_null_registry_disables_engine_metrics(tiny):

@@ -506,8 +506,8 @@ class TestPerLayerCopies:
         src_page, dst_page = 2, 5
         rows = np.arange(src_page * ps, (src_page + 1) * ps)
         for i, lyr in enumerate(runner.cache["layers"]):
-            H, D = lyr["self"]["k"].shape[1:]
-            vals = np.full((ps, H, D), float(i + 1), np.float32)
+            vals = np.full((ps, lyr["self"]["k"].shape[1]), float(i + 1),
+                           np.float32)
             lyr["self"]["k"] = lyr["self"]["k"].at[rows].set(vals)
             lyr["self"]["v"] = lyr["self"]["v"].at[rows].set(-vals)
         eng.pool.pending_copies.append((src_page, dst_page))

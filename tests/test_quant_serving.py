@@ -251,7 +251,7 @@ class TestInt8PagedCache:
                                  kv_dtype="int8")
         lc = cache["layers"][0]["self"]
         assert lc["k"].dtype == jnp.int8
-        assert lc["k"].shape == (16 * 8, 2, 32)
+        assert lc["k"].shape == (16 * 8, 2 * 32)
         assert lc["k_scale"].dtype == jnp.float32
         assert lc["k_scale"].shape == (16 * 8, 2)
         fp = model.init_cache(2, 64, page_size=8, n_pages=16)
@@ -313,7 +313,7 @@ class TestTpSpecs:
         specs = paged_cache_specs(jax.eval_shape(lambda: cache),
                                   axis="model")
         lc = specs["layers"][0]["self"]
-        assert lc["k"] == P(None, "model", None)
+        assert lc["k"] == P(None, "model")
         assert lc["k_scale"] == P(None, "model")
         assert lc["v_scale"] == P(None, "model")
 

@@ -271,25 +271,45 @@ class TestPagedKernel:
         for window in (0, 3):
             ref = paged_decode_attention_ref(jnp.asarray(q), kp, vp, bt,
                                              lens, window)
-            ker = paged_decode_attention(jnp.asarray(q), jnp.asarray(kp),
-                                         jnp.asarray(vp), bt, lens, window,
-                                         scale=D ** -0.5, interpret=True)
+            ker = paged_decode_attention(
+                jnp.asarray(q), jnp.asarray(kp.reshape(P, ps, Hkv * D)),
+                jnp.asarray(vp.reshape(P, ps, Hkv * D)), bt, lens, window,
+                scale=D ** -0.5, interpret=True)
             np.testing.assert_allclose(np.asarray(ref), np.asarray(ker),
                                        rtol=1e-5, atol=1e-5)
 
-    @pytest.mark.parametrize("window", [0, 3, 6])
+    # windows of 3 and 6 slots start mid-page; 83 slots start five pages
+    # and three slots back, mid-block for every lane it cuts
+    @pytest.mark.parametrize("window", [0, 3, 6, 83])
     def test_walk_reads_only_visible_pages(self, window):
         """Ragged lanes in one batch over a pool in which every page no
         lane can see is NaN: unmapped pages, pages past a lane's last
         slot and pages before its window.  A page the kernel scored
         would poison ``p @ v`` even fully masked, so a finite output
         equal to the oracle's (run with those pages zeroed) shows the
-        walk never touches them.  The idle lane (``kv_len`` 0) returns
+        walk never touches them.  Lanes end one slot into a block,
+        exactly on a block boundary and one slot before one, so a last
+        block's rows past the lane's last page are never fetched and
+        never reach ``p @ v``.  The idle lane (``kv_len`` 0) returns
         0."""
-        from repro.kernels.decode_attention import paged_decode_attention
+        # (page size, pages a lane): a table shorter than a block, so the
+        # block is clamped to the table, and a table of several blocks
+        for ps, mp in ((4, 4), (16, 40)):
+            self._check_walk(window, ps, mp)
+
+    @staticmethod
+    def _check_walk(window, ps, mp):
+        from repro.kernels.decode_attention import (BLOCK_TOKENS,
+                                                    pages_per_block,
+                                                    paged_decode_attention)
         from repro.kernels.ref import paged_decode_attention_ref
-        Hkv, G, D, ps, mp = 2, 2, 8, 4, 4
-        lens = np.array([0, 1, ps, ps + 1, mp * ps], np.int32)
+        Hkv, G, D = 2, 2, 8
+        ppb = pages_per_block(ps, mp)
+        assert ppb == min(mp, BLOCK_TOKENS // ps)
+        assert ppb == mp if mp == 4 else mp // ppb >= 3
+        blk = ppb * ps
+        lens = [0, 1, ps, ps + 1, blk + 1, 2 * blk, 2 * blk - 1, mp * ps]
+        lens = np.array(sorted({n for n in lens if n <= mp * ps}), np.int32)
         B, P = len(lens), 1 + len(lens) * mp
         rng = np.random.default_rng(window)
         bt = (1 + rng.permutation(B * mp)).reshape(B, mp).astype(np.int32)
@@ -302,15 +322,43 @@ class TestPagedKernel:
             seen[bt[b, lo // ps:(n + ps - 1) // ps]] = True
         hidden = ~seen[:, None, None, None]
         ker = np.asarray(paged_decode_attention(
-            q, jnp.asarray(np.where(hidden, np.nan, kp)),
-            jnp.asarray(np.where(hidden, np.nan, vp)), bt, lens, window,
-            scale=D ** -0.5, interpret=True))
+            q, jnp.asarray(np.where(hidden, np.nan, kp).reshape(P, ps, -1)),
+            jnp.asarray(np.where(hidden, np.nan, vp).reshape(P, ps, -1)),
+            bt, lens, window, scale=D ** -0.5, interpret=True))
         ref = np.asarray(paged_decode_attention_ref(
             q, jnp.asarray(np.where(hidden, 0.0, kp)),
             jnp.asarray(np.where(hidden, 0.0, vp)), bt, lens, window))
         assert np.isfinite(ker).all()
         np.testing.assert_allclose(ker, ref, rtol=1e-5, atol=1e-5)
         np.testing.assert_array_equal(ker[0], 0.0)
+
+
+    def test_walk_plan_fetches_each_visible_page_once(self):
+        """The walk's plan, lane by lane: its blocks cover exactly the
+        pages from its first to its last visible one, in
+        ``cdiv(pages, pages_per_block)`` blocks; each lane's first block
+        takes the buffer slot after the previous live lane's last; and
+        each lane hands over to the next lane with a block to fetch."""
+        from repro.kernels import decode_attention as da
+        ps, mp, window = 16, 40, 83
+        ppb = da.pages_per_block(ps, mp)
+        lens = np.array([0, 1, 129, 256, 255, 0, 640, 17], np.int32)
+        plan = np.asarray(da._walk_plan(jnp.asarray(lens), window, ps, mp,
+                                        ppb))
+        first, last, n_blocks, start, nxt = plan
+        live = [b for b, n in enumerate(lens) if n > 0]
+        assert nxt[0] == live[0] and nxt[-1] == len(lens)
+        walked = 0
+        for b, n in enumerate(lens):
+            lo = max(n - window, 0) // ps
+            want = list(range(lo, -(-n // ps))) if n else []
+            got = [first[b] + j * ppb + i for j in range(n_blocks[b])
+                   for i in range(ppb) if first[b] + j * ppb + i <= last[b]]
+            assert got == want, b
+            assert n_blocks[b] == -(-len(want) // ppb)
+            assert start[b] == walked
+            walked += n_blocks[b]
+            assert nxt[b + 1] == next((c for c in live if c > b), len(lens))
 
 
 class TestScanEscapeLayout:
@@ -341,9 +389,9 @@ class TestScanEscapeLayout:
                 + np.tile(np.arange(ps), ctx // ps)
                 for b in range(B)])
             for i, lyr in enumerate(cache["layers"]):
-                H, D = lyr["self"]["k"].shape[1:]
-                vals = (np.arange(len(rows) * H * D, dtype=np.float32)
-                        .reshape(len(rows), H, D) % 7 - 3) * 0.1 * (i + 1)
+                HD = lyr["self"]["k"].shape[1]
+                vals = (np.arange(len(rows) * HD, dtype=np.float32)
+                        .reshape(len(rows), HD) % 7 - 3) * 0.1 * (i + 1)
                 lyr["self"]["k"] = lyr["self"]["k"].at[rows].set(vals)
                 lyr["self"]["v"] = lyr["self"]["v"].at[rows].set(-vals)
         return cache
@@ -353,7 +401,7 @@ class TestScanEscapeLayout:
         cache = model.init_cache(2, 32, page_size=4, n_pages=9)
         layers = cache["layers"]
         assert isinstance(layers, list) and len(layers) == cfg.n_layers
-        shape = (9 * 4, cfg.n_kv_heads, 64 // 4)
+        shape = (9 * 4, cfg.n_kv_heads * (64 // 4))
         for lyr in layers:
             assert lyr["self"]["k"].shape == shape
             assert lyr["self"]["v"].shape == shape

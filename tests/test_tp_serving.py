@@ -261,7 +261,9 @@ def test_tp_donation_aliases_per_shard_buffers():
     r = eng.core.runner
     assert r.tp_shards == 2
     k = r.cache["layers"][0]["self"]["k"]
-    assert [s.data.shape[1] for s in k.addressable_shards] == [1, 1]
+    # each shard holds one kv head's columns of every row
+    assert [s.data.shape[1] for s in k.addressable_shards] == (
+        [k.shape[1] // 2] * 2)
     ptrs0 = sorted(s.data.unsafe_buffer_pointer()
                    for s in k.addressable_shards)
     logits = r.decode(np.zeros((4, 1), np.int32),

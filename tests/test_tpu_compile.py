@@ -55,28 +55,90 @@ def _compiled_text(fn, sharding, *shapes) -> str:
 # qwen3-1.7b decode: 8 slots, 8 kv heads (2 per chip under 4-way TP),
 # GQA group 2, head_dim 128, 16-token pages, max_len 1024; the
 # granite-3-8b pipeline stage: 32 lanes x 96 pages (max_len 1536), 32
-# query / 8 kv heads, scores times Granite's attention_multiplier 1/128
+# query / 8 kv heads, scores times Granite's attention_multiplier 1/128;
+# the qwen3-1.7b.chat cell's decode: 32 lanes x 128 pages (max_len 2048)
 @pytest.mark.parametrize("B,hkv,G,max_pages,scale", [
     (8, 8, 2, 64, 128 ** -0.5), (8, 2, 2, 64, 128 ** -0.5),
-    (32, 8, 4, 96, 1 / 128)], ids=["hkv8", "hkv2_tp_local", "granite_stage"])
+    (32, 8, 4, 96, 1 / 128), (32, 8, 2, 128, 128 ** -0.5)],
+    ids=["hkv8", "hkv2_tp_local", "granite_stage", "chat_decode"])
 def test_paged_decode_attention_compiles(one_chip, B, hkv, G, max_pages,
                                          scale):
     from repro.kernels.decode_attention import paged_decode_attention
     D, ps = 128, 16
     n_pages = 1 + B * max_pages
 
+    # the pool as the paged cache holds it, one (rows, Hkv * D) buffer,
+    # viewed per page as the ops wrapper does
     def fn(q, kp, vp, bt, lens):
-        return paged_decode_attention(q, kp, vp, bt, lens, 0, scale=scale,
-                                      interpret=False)
+        return paged_decode_attention(
+            q, kp.reshape(n_pages, ps, hkv * D),
+            vp.reshape(n_pages, ps, hkv * D), bt, lens, 0, scale=scale,
+            interpret=False)
 
     text = _compiled_text(
         fn, one_chip,
         ((B, hkv, G, D), jnp.bfloat16),
-        ((n_pages, ps, hkv, D), jnp.bfloat16),
-        ((n_pages, ps, hkv, D), jnp.bfloat16),
+        ((n_pages * ps, hkv * D), jnp.bfloat16),
+        ((n_pages * ps, hkv * D), jnp.bfloat16),
         ((B, max_pages), jnp.int32),
         ((B,), jnp.int32))
     assert "tpu_custom_call" in text
+    assert _pool_copies(text, n_pages * ps * hkv * D) == []
+
+
+@pytest.mark.parametrize("kv_dtype", ["fp32", "int8"])
+def test_paged_decode_step_copies_no_pool(one_chip, monkeypatch, kv_dtype):
+    """A whole decode step over the paged cache at qwen3-1.7b's attention
+    widths (8 kv heads of 128, 16-token pages, 32 lanes × 128 pages):
+    the Pallas kernel and the int8 pages' gather read each layer's pool
+    buffer where it lies, so the program holds no pool-sized array but
+    the buffers themselves and their in-place row writes."""
+    import dataclasses
+    import math
+    import repro.kernels.ops as ops
+    from repro.configs.qwen3_1p7b import CONFIG
+    from repro.models import build_model
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    cfg = dataclasses.replace(CONFIG, n_layers=1, d_model=256, d_ff=512,
+                              vocab_size=1024)
+    model = build_model(cfg)
+    B, max_len, ps = 32, 2048, 16
+    sds = lambda t: jax.tree.map(      # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        t)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    cache = jax.eval_shape(lambda: model.init_cache(
+        B, max_len, page_size=ps, kv_dtype=kv_dtype))
+    step = jax.jit(lambda p, c, t, pos: model.decode_step(
+        p, c, t, pos, page_size=ps), donate_argnums=1)
+    text = step.lower(sds(params), sds(cache),
+                      sds(jax.ShapeDtypeStruct((B, 1), jnp.int32)),
+                      sds(jax.ShapeDtypeStruct((B,), jnp.int32))
+                      ).compile().as_text()
+    pool = cache["layers"][0]["self"]["k"]
+    assert _pool_copies(text, math.prod(pool.shape)) == []
+    if kv_dtype == "fp32":
+        assert "tpu_custom_call" in text
+
+
+def _pool_copies(text: str, pool_elems: int):
+    """Instructions of a compiled program that produce an array of the
+    page pool's size other than by a parameter, a bitcast or the
+    in-place write of the step's rows (a scatter, or the fusion XLA
+    makes of one): a relayout of the pool (a reshape to another tiling,
+    a copy), which on a TPU moves every byte of it on every call."""
+    import math
+    import re
+    out = []
+    for ln in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = \w+\[([\d,]*)\]\S* (\S+?)\(", ln)
+        if (m and m.group(2) not in ("parameter", "bitcast", "scatter")
+                and not (m.group(2) == "fusion"
+                         and re.search(r'op_name="[^"]*/scatter"', ln))
+                and math.prod(int(d) for d in m.group(1).split(",") if d)
+                == pool_elems):
+            out.append(ln.strip()[:120])
+    return out
 
 
 def _readers_kernel_patterns():
@@ -107,8 +169,10 @@ def test_paged_kernel_instruction_name(one_chip, via):
     n_pages = 1 + B * max_pages
 
     def kernel(q, kp, vp, bt, lens):
-        return paged_decode_attention(q, kp, vp, bt, lens, 0,
-                                      scale=D ** -0.5, interpret=False)
+        return paged_decode_attention(
+            q, kp.reshape(n_pages, ps, hkv * D),
+            vp.reshape(n_pages, ps, hkv * D), bt, lens, 0,
+            scale=D ** -0.5, interpret=False)
 
     wrapped = jax.jit(kernel)
 
@@ -125,8 +189,8 @@ def test_paged_kernel_instruction_name(one_chip, via):
     text = _compiled_text(
         fn, one_chip,
         ((B, hkv, G, D), jnp.bfloat16),
-        ((n_pages, ps, hkv, D), jnp.bfloat16),
-        ((n_pages, ps, hkv, D), jnp.bfloat16),
+        ((n_pages * ps, hkv * D), jnp.bfloat16),
+        ((n_pages * ps, hkv * D), jnp.bfloat16),
         ((B, max_pages), jnp.int32),
         ((B,), jnp.int32))
     lines = [ln.strip() for ln in text.splitlines()]
