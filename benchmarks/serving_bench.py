@@ -687,10 +687,10 @@ def _tp_child() -> None:
     arrivals = np.cumsum(rng.exponential(0.05, size=len(reqs))).tolist()
     max_len = max(len(r.prompt) for r in reqs) + 12 + 8
 
-    def run(mesh=None, n_nodes=1):
+    def run(mesh=None):
         eng = ContinuousServingEngine(
             model, params, max_len=max_len, max_running=8, page_size=8,
-            mesh=mesh, n_nodes=n_nodes)
+            mesh=mesh)
         eng.generate(reqs[:3])      # warm every prompt-length bucket
         t0 = time.perf_counter()
         comps = eng.generate(reqs, arrivals=arrivals)
@@ -708,7 +708,7 @@ def _tp_child() -> None:
     out = {"parity": True}
     for s in TP_SHARDS:
         mesh = make_mesh((s,), ("model",))
-        eng, (tokens, toks_per_s, ttft_p50) = run(mesh, n_nodes=s)
+        eng, (tokens, toks_per_s, ttft_p50) = run(mesh)
         out[f"s{s}"] = {"decode_toks_per_s": toks_per_s,
                         "ttft_p50_ms": ttft_p50 * 1e3}
         out["parity"] = out["parity"] and tokens == ref_tokens

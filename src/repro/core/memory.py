@@ -22,7 +22,7 @@ is minimal under the parity policy, UMA vs NUMA placement bytes match).
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence
 
 from .tensor import OpType, TensorHeader
 
@@ -43,18 +43,12 @@ class Allocation:
 
 @dataclasses.dataclass
 class Pool:
-    """A pre-allocated memory pool bound to one NUMA node (or UMA).
-
-    ``shard_id`` is set only for KV page pools planned over a TP mesh
-    (``plan_kv_pages(n_shards=)``): the mesh shard holding this pool's
-    head-slice of every page resident on ``node_id``.
-    """
+    """A pre-allocated memory pool bound to one NUMA node (or UMA)."""
 
     name: str
     node_id: Optional[int]  # None = UMA / replicated
     cursor: int = 0
     peak: int = 0
-    shard_id: Optional[int] = None
     allocations: Dict[str, Allocation] = dataclasses.field(default_factory=dict)
 
     def alloc(self, name: str, nbytes: int) -> Allocation:
@@ -87,9 +81,6 @@ class MemoryManager:
         self.double_buffer = double_buffer
         self.weight_pools: List[Pool] = []
         self.act_pools: List[List[Pool]] = []  # [node][parity]
-        self.kv_pools: List[Pool] = []         # populated by plan_kv_pages
-        self._kv_nodes = 1                     # nodes the KV plan stripes
-        self._kv_shards = 1                    # TP shards per page
         if self.numa:
             for i in range(n_nodes):
                 self.weight_pools.append(Pool(f"weights/node{i}", i))
@@ -156,97 +147,6 @@ class MemoryManager:
         return plan
 
     # ------------------------------------------------------------------
-    # KV-cache page pools (serving)
-    # ------------------------------------------------------------------
-    def plan_kv_pages(self, n_pages: int, page_bytes: int, *,
-                      n_shards: int = 1) -> List[Allocation]:
-        """Carve the serving KV cache into fixed-size pages, one carve-out
-        per page, striped round-robin across the node pools.
-
-        The paged KV pool (``repro.serving.kv_pool``) is the runtime
-        allocator on top of this plan: a page's *placement* (node, pool
-        offset) is decided here at startup, exactly like weights and
-        activations, while which *sequence* owns the page changes at
-        runtime without moving bytes — ArcLight's pre-allocate-then-bind
-        discipline (§2.3) applied to the serving cache.  Returns the
-        per-page allocations indexed by page id.
-
-        ``n_shards`` > 1 is the tensor-parallel serving layout: the
-        page pool is **head-sharded** over the mesh's ``model`` axis, so
-        every page's bytes live 1/S on each of the S shards.  Planning
-        then carves one ``page_bytes / n_shards`` region per (node,
-        shard) pool for every page — ``kv_page_placement`` reports the
-        page's (node, shard byte map) and the per-page return value is
-        the page's *node-local shard-0* allocation (offsets are
-        identical on every shard of the node, so one allocation
-        describes all S carve-outs).  Page *rows* never move between
-        nodes and head-slices never move between shards: the block
-        table is replicated and all runtime ownership changes stay
-        host-side, exactly as in the single-shard plan.
-        """
-        if self.kv_pools:
-            raise ValueError("KV pages already planned")
-        if n_shards < 1:
-            raise ValueError("n_shards must be >= 1")
-        if page_bytes % n_shards:
-            raise ValueError(
-                f"page_bytes={page_bytes} does not split over "
-                f"{n_shards} shards")
-        self._kv_shards = n_shards
-        node_ids = list(range(self.n_nodes)) if self.numa else [None]
-        self._kv_nodes = len(node_ids)
-        self.kv_pools = []
-        for i in node_ids:
-            tag = f"node{i}" if i is not None else "uma"
-            if n_shards == 1:
-                self.kv_pools.append(Pool(f"kv/{tag}", i))
-            else:
-                self.kv_pools.extend(
-                    Pool(f"kv/{tag}/shard{s}", i, shard_id=s)
-                    for s in range(n_shards))
-        allocs = []
-        shard_bytes = page_bytes // n_shards
-        for pid in range(n_pages):
-            node_idx = pid % self._kv_nodes
-            first: Optional[Allocation] = None
-            for pool in self.kv_pools[node_idx * n_shards:
-                                      (node_idx + 1) * n_shards]:
-                a = pool.alloc(f"kv_page{pid}", shard_bytes)
-                first = first if first is not None else a
-            assert first is not None
-            allocs.append(first)
-        return allocs
-
-    def kv_page_node(self, page_id: int) -> int:
-        """NUMA node a planned page is resident on (0 under UMA)."""
-        if not self.kv_pools:
-            raise ValueError("no KV pages planned")
-        node_id = self.kv_pools[
-            (page_id % self._kv_nodes) * self._kv_shards].node_id
-        return node_id or 0
-
-    def kv_page_placement(self, page_id: int) -> Tuple[int, Tuple[int, ...]]:
-        """(node, shards) of a planned page: the NUMA node its rows are
-        bound to and the mesh shards its bytes live on — every shard
-        under head-sharded TP (each holds the page's local head slice),
-        just ``(0,)`` in the single-shard plan."""
-        return (self.kv_page_node(page_id), tuple(range(self._kv_shards)))
-
-    @property
-    def kv_node_count(self) -> int:
-        """Distinct NUMA nodes the KV plan stripes pages across."""
-        if not self.kv_pools:
-            raise ValueError("no KV pages planned")
-        return self._kv_nodes
-
-    @property
-    def kv_shard_count(self) -> int:
-        """Mesh shards each KV page's bytes are split over (1 = no TP)."""
-        if not self.kv_pools:
-            raise ValueError("no KV pages planned")
-        return self._kv_shards
-
-    # ------------------------------------------------------------------
     # accounting
     # ------------------------------------------------------------------
     def weight_bytes(self) -> Dict[str, int]:
@@ -255,13 +155,9 @@ class MemoryManager:
     def activation_bytes(self) -> Dict[str, int]:
         return {p.name: p.peak for pools in self.act_pools for p in pools}
 
-    def kv_bytes(self) -> Dict[str, int]:
-        return {p.name: p.peak for p in self.kv_pools}
-
     def total_bytes(self) -> int:
         return (sum(self.weight_bytes().values())
-                + sum(self.activation_bytes().values())
-                + sum(self.kv_bytes().values()))
+                + sum(self.activation_bytes().values()))
 
     def per_node_bytes(self) -> Dict[int, int]:
         """Bytes resident in each node's local memory."""
@@ -271,8 +167,6 @@ class MemoryManager:
         for pools in self.act_pools:
             for p in pools:
                 out[p.node_id or 0] = out.get(p.node_id or 0, 0) + p.peak
-        for p in self.kv_pools:
-            out[p.node_id or 0] = out.get(p.node_id or 0, 0) + p.peak
         return out
 
 

@@ -4,8 +4,8 @@ Defined as FUNCTIONS so importing this module never touches jax device
 state; ``dryrun.py`` sets ``XLA_FLAGS=--xla_force_host_platform_device_count=512``
 before any jax import, tests/benches see the single real CPU device.
 
-TPU v5e mapping (DESIGN.md §5): ``model`` is the NUMA-node analogue —
-the axis the paper's §3.2 weight partitions live on; ``data`` carries
+TPU v5e mapping (DESIGN.md §5): ``model`` is the axis the paper's
+§3.2 weight and head partitions live on; ``data`` carries
 batch (and the KV sequence for long_500k); ``pod`` is cross-pod data
 parallelism (2 pods × 256 chips).
 """

@@ -146,18 +146,13 @@ def _serve_replicated(args) -> int:
 
 
 def _print_shard_stats(pool) -> None:
-    """Per-shard / per-node KV pool residency under --tp-shards: every
-    shard reserves its head slice of every node's pages."""
-    shard = pool.capacity_bytes_per_shard()
-    node = pool.capacity_bytes_per_node()
-    live = pool.live_bytes_per_node()
+    """Per-shard KV pool residency under --tp-shards: every shard holds
+    its head slice of every page."""
+    live = pool.n_live() * pool.cfg.page_shard_bytes
     print("tp pool: "
-          + ", ".join(f"shard{s} {b / 1024:.0f} KiB"
-                      for s, b in sorted(shard.items())))
-    print("tp pages: "
-          + ", ".join(f"node{n} {live.get(n, 0) / 1024:.0f}"
-                      f"/{b / 1024:.0f} KiB live"
-                      for n, b in sorted(node.items())))
+          + ", ".join(f"shard{s} {live / 1024:.0f}/{b / 1024:.0f} KiB live"
+                      for s, b in sorted(
+                          pool.capacity_bytes_per_shard().items())))
 
 
 def main() -> int:
@@ -206,9 +201,9 @@ def main() -> int:
                          "to K=0 (docs/serving.md)")
     ap.add_argument("--tp-shards", type=int, default=1,
                     help="continuous/async engines: tensor-parallel "
-                         "shards — forces that many host devices "
-                         "(shard ≅ NUMA node), head-shards the KV page "
-                         "pools over the mesh's 'model' axis")
+                         "shards — forces that many host devices on "
+                         "the CPU, head-shards the KV pages over the "
+                         "mesh's 'model' axis")
     ap.add_argument("--warmup-steps", type=int, default=40,
                     help="brief LM warm-up so outputs aren't noise "
                          "(0 = random weights)")
@@ -346,7 +341,7 @@ def main() -> int:
                      f"--tp-shards {args.tp_shards} ({hint})")
         mesh = make_mesh((args.tp_shards,), ("model",))
         print(f"tp mesh: {args.tp_shards}-way 'model' axis over "
-              f"{devs[0].platform} devices (shard ≅ NUMA node)")
+              f"{devs[0].platform} devices")
 
     if args.warmup_steps:
         print(f"warm-up training ({args.warmup_steps} steps) ...")
@@ -404,8 +399,7 @@ def main() -> int:
             if (args.interactive or args.http) else max_len,
             max_running=args.max_running, page_size=args.page_size,
             n_pages=args.n_pages, prefill_chunk=args.prefill_chunk,
-            prefix_cache=not args.no_prefix_cache, mesh=mesh,
-            n_nodes=max(args.tp_shards, 1), quant=quant,
+            prefix_cache=not args.no_prefix_cache, mesh=mesh, quant=quant,
             spec_decode=args.spec_decode, tracer=tracer)
         if args.http:        # --replicas 0: in-process engine over HTTP
             from ..serving.http import HttpFrontend
@@ -468,8 +462,7 @@ def main() -> int:
             model, params, max_len=max_len, max_running=args.max_running,
             page_size=args.page_size, n_pages=args.n_pages,
             prefill_chunk=args.prefill_chunk,
-            prefix_cache=not args.no_prefix_cache, mesh=mesh,
-            n_nodes=max(args.tp_shards, 1), quant=quant,
+            prefix_cache=not args.no_prefix_cache, mesh=mesh, quant=quant,
             spec_decode=args.spec_decode, tracer=tracer)
         comps = eng.generate(reqs)
         st = eng.pool.stats

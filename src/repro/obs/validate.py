@@ -15,13 +15,13 @@ Two documents leave the serving stack (``docs/observability.md``):
 The module doubles as the smoke gate's CLI::
 
     python -m repro.obs.validate --metrics M.json --trace T.jsonl \
-        [--require-gauge kv_pool.pages_free:node,shard] \
+        [--require-gauge kv_pool.pages_free] \
         [--require-counter router.requests:replica]
 
 ``--require-gauge`` / ``--require-counter`` (``NAME[:label,label]``)
 additionally assert the snapshot contains that series with the given
-label keys — how ``tools/check.sh --smoke`` pins the per-(node, shard)
-pool gauges of a ``--tp-shards 2`` run and the per-replica ``router.*``
+label keys — how ``tools/check.sh --smoke`` pins the pool's free-page
+gauge of a ``--tp-shards 2`` run and the per-replica ``router.*``
 series of a ``--http --replicas 2`` run.  Exit 0 = all documents valid.
 """
 

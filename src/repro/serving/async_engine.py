@@ -135,10 +135,10 @@ class PollResult:
 class AsyncEngine:
     """Live submit/stream/poll over a background ``EngineCore`` stepper.
 
-    Constructor keywords mirror ``ContinuousServingEngine`` (they are
-    forwarded to :class:`EngineCore`).  Use as a context manager or
-    call :meth:`shutdown` explicitly — the stepper is a daemon thread,
-    but an orderly join is what tests and servers want.
+    Keyword options are :class:`EngineCore`'s, forwarded as given.  Use
+    as a context manager or call :meth:`shutdown` explicitly — the
+    stepper is a daemon thread, but an orderly join is what tests and
+    servers want.
     """
 
     def __init__(self, model: Model, params: Any, *,

@@ -26,24 +26,11 @@ from .engine import Completion, Request
 
 
 class ContinuousServingEngine:
-    def __init__(self, model: Model, params: Any, *, max_len: int = 1024,
-                 max_running: int = 8, page_size: int = 16,
-                 n_pages: Optional[int] = None, n_nodes: int = 1,
-                 numa: bool = True,
-                 prefill_chunk: Optional[int] = None,
-                 prefix_cache: bool = True,
-                 window_override: Optional[int] = None,
-                 mesh=None, policy=None, quant=None, spec_decode: int = 0,
-                 seed: int = 0, clock: Optional[Clock] = None,
-                 registry=None, tracer=None) -> None:
-        self.core = EngineCore(
-            model, params, max_len=max_len, max_running=max_running,
-            page_size=page_size, n_pages=n_pages, n_nodes=n_nodes,
-            numa=numa, prefill_chunk=prefill_chunk,
-            prefix_cache=prefix_cache, window_override=window_override,
-            mesh=mesh, policy=policy, quant=quant,
-            spec_decode=spec_decode, seed=seed, clock=clock,
-            registry=registry, tracer=tracer)
+    """Keyword options are :class:`EngineCore`'s, forwarded as given."""
+
+    def __init__(self, model: Model, params: Any, *,
+                 clock: Optional[Clock] = None, **core_kwargs) -> None:
+        self.core = EngineCore(model, params, clock=clock, **core_kwargs)
         self.decode_gaps_s: List[float] = []
         self.last_phase_s: Dict[str, float] = {}
 

@@ -122,8 +122,7 @@ class EngineCore:
 
     def __init__(self, model: Model, params, *, max_len: int = 1024,
                  max_running: int = 8, page_size: int = 16,
-                 n_pages: Optional[int] = None, n_nodes: int = 1,
-                 numa: bool = True,
+                 n_pages: Optional[int] = None,
                  prefill_chunk: Optional[int] = None,
                  prefix_cache: bool = True,
                  window_override: Optional[int] = None,
@@ -167,17 +166,14 @@ class EngineCore:
         self.tracer = tracer if tracer is not None else NullTracer()
         self._key = jax.random.PRNGKey(seed)
 
-        # mesh mode (TP serving): each mesh shard stands in for one
-        # NUMA node (the paper's node≅shard mapping), so page planning
-        # stripes rows across n_nodes AND splits every page's bytes across
-        # the shards' head slices (KVPoolConfig.n_shards)
+        # mesh mode (TP serving): every page's bytes split across the
+        # shards' head slices (KVPoolConfig.n_shards)
         n_shards = (int(mesh.shape.get("model", 1))
                     if mesh is not None else 1)
         self.pool = KVCachePool(KVPoolConfig(
             n_pages=n_pages, page_size=page_size, n_layers=cfg.n_layers,
             n_kv_heads=cfg.n_kv_heads, head_dim=cfg.resolved_head_dim,
-            dtype_bytes=np.dtype(cfg.dtype).itemsize, n_nodes=n_nodes,
-            numa=numa, n_shards=n_shards,
+            dtype_bytes=np.dtype(cfg.dtype).itemsize, n_shards=n_shards,
             kv_dtype=quant.kv_dtype), prefix_cache=prefix_cache)
         self.pool.bind_registry(self.registry)
         self.scheduler = ContinuousScheduler(
@@ -260,21 +256,14 @@ class EngineCore:
                 "per-lane fraction of drafted tokens accepted each "
                 "verify step",
                 buckets=tuple(i / 8 for i in range(1, 9))).labels()
-        # per-(node, shard) pool gauges, sampled after every step; a
-        # page's bytes are split across every shard's head-slice pool,
-        # so each shard sees the same per-node free count.  Skipped
-        # entirely under NullRegistry (no per-step dict build).
-        self._pool_gauges: List[Tuple[object, int]] = []
-        self._g_retained = None
+        # pool gauges, sampled after every step; skipped entirely under
+        # NullRegistry
+        self._g_free = self._g_retained = None
         if not isinstance(reg, NullRegistry):
-            g_free = reg.gauge(
+            self._g_free = reg.gauge(
                 "kv_pool.pages_free",
-                "allocatable pages on this NUMA node as seen by this "
-                "TP shard's head-slice pool")
-            for node in range(max(self.pool.mm.kv_node_count, 1)):
-                for shard in range(n_shards):
-                    self._pool_gauges.append(
-                        (g_free.labels(node=node, shard=shard), node))
+                "pages on the pool's free list (retained pages "
+                "excluded)").labels()
             self._g_retained = reg.gauge(
                 "kv_pool.pages_retained",
                 "refcount-0 prefix pages parked in the retention LRU",
@@ -557,11 +546,10 @@ class EngineCore:
             self._c_tok_decode.inc(n_emitted)
             self._h_occupancy.observe(float(len(plan.decodes)))
 
-        if self._pool_gauges:
-            free = self.pool.free_pages_by_node()
-            for g, node in self._pool_gauges:
-                g.set(free.get(node, 0))
-            self._g_retained.set(self.pool.n_retained())
+        if self._g_free is not None:
+            n_retained = self.pool.n_retained()
+            self._g_free.set(self.pool.n_free() - n_retained)
+            self._g_retained.set(n_retained)
 
         return res
 

@@ -1,27 +1,21 @@
-"""NUMA-aware paged KV-cache pool with refcounted prefix sharing.
+"""Paged KV-cache pool with refcounted prefix sharing.
 
-ArcLight's §2.3 memory discipline — pre-allocate node-bound pools at
-startup, then *bind* rather than *allocate* at runtime — applied to the
-serving KV cache.  The physical cache is a fixed pool of fixed-size
-**pages** (``page_size`` token slots each, all layers of a page
-co-resident on one NUMA node).  At runtime a sequence owns an ordered
+ArcLight's §2.3 memory discipline — pre-allocate at startup, then
+*bind* rather than *allocate* at runtime — applied to the serving KV
+cache.  The physical cache is a fixed pool of fixed-size **pages**
+(``page_size`` token slots each, all layers of a page at the same rows
+of every layer's device buffer).  At runtime a sequence owns an ordered
 list of pages (its *block table*); admission, growth, sharing and
 eviction move page *references* around on the host without ever moving
 cache bytes on the device (the one exception: copy-on-write, which
 emits an explicit page-copy the engine applies).
 
-Placement is planned through :class:`repro.core.memory.MemoryManager`
-(``plan_kv_pages``), so KV pages sit in the same per-node accounting as
-weights and activations: pages stripe round-robin across node pools and
-``MemoryManager.per_node_bytes`` reports the whole model's residency.
-On TPU the "node" is a mesh shard; on CPU it is a NUMA node the engine
-would ``mbind`` the page's carve-out to.  Under **tensor-parallel
-serving** (``KVPoolConfig.n_shards`` > 1, the engine's ``mesh=`` mode)
-a page's rows still stripe across nodes, but its *bytes* split across
-the mesh shards — each shard holds the page's local kv-head slice in a
-per-(node, shard) pool (``kv_page_placement``).  Nothing else here
-changes: page ids are global, so refcounts, the prefix map, retention
-and CoW plans are shard-agnostic host bookkeeping.
+Free pages sit on one LIFO list, filled so that page 1 is handed out
+first.  Under **tensor-parallel serving** (``KVPoolConfig.n_shards`` >
+1, the engine's ``mesh=`` mode) every page's *bytes* split across the
+mesh shards — each shard holds the page's local kv-head slice — while
+page ids stay global, so the free list, refcounts, the prefix map,
+retention and CoW plans are shard-agnostic host bookkeeping.
 
 Prefix caching: KV bytes are a pure
 function of ``(token values, absolute positions)``, so two requests
@@ -56,15 +50,14 @@ Invariants (property-tested in ``tests/test_serving_paged.py`` and
   positions write into;
 * **refcount lifecycle** — every page in any live block table has
   refcount >= 1; a page leaves the live set exactly when its refcount
-  drops to 0 (to its node free-list, or to the retained LRU when it is
+  drops to 0 (to the free list, or to the retained LRU when it is
   prefix-indexed); ``release``/``free`` only ever decrement, so a
   shared page outlives any single owner;
 * **immutability of shared pages** — a page with refcount > 1 is never
   written: writers go through :meth:`ensure_writable`, which swaps in a
   private copy-on-write page first;
-* freed pages return to their node free-list and are reused (LIFO, so
-  recently-touched — cache-warm — pages are preferred);
-* per-node live-byte accounting never exceeds the planned capacity.
+* freed pages return to the free list and are reused (LIFO, so
+  recently-touched — cache-warm — pages are preferred).
 """
 
 from __future__ import annotations
@@ -74,8 +67,6 @@ from collections import OrderedDict
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
-
-from ..core.memory import MemoryManager
 
 
 @dataclasses.dataclass(frozen=True)
@@ -93,11 +84,8 @@ class KVPoolConfig:
     n_kv_heads: int
     head_dim: int
     dtype_bytes: int = 4
-    n_nodes: int = 1
-    numa: bool = True
     #: tensor-parallel mesh shards the pool is head-sharded over: each
-    #: page's bytes live 1/S on every shard (kv heads split S ways), so
-    #: planning carves a per-(node, shard) region for every page
+    #: page's bytes live 1/S on every shard (kv heads split S ways)
     n_shards: int = 1
     #: KV page element format ("fp32" | "int8" — the engine's
     #: ``--kv-dtype``).  "int8" pages hold 1-byte codes plus one f32
@@ -277,8 +265,7 @@ class KVCachePool:
     """Free-list page allocator with refcounted, prefix-shared block
     tables (see module docstring for the invariants)."""
 
-    def __init__(self, cfg: KVPoolConfig,
-                 mm: Optional[MemoryManager] = None, *,
+    def __init__(self, cfg: KVPoolConfig, *,
                  prefix_cache: bool = True, retain: bool = True) -> None:
         if cfg.n_pages < 2:
             raise ValueError("need at least one usable page besides scratch")
@@ -287,26 +274,21 @@ class KVCachePool:
             raise ValueError(
                 f"{cfg.n_kv_heads} kv heads do not head-shard over "
                 f"{cfg.n_shards} mesh shards")
-        self.mm = mm if mm is not None else MemoryManager(
-            cfg.n_nodes, numa=cfg.numa)
-        self.mm.plan_kv_pages(cfg.n_pages, cfg.page_bytes,
-                              n_shards=cfg.n_shards)
-        self._free: Dict[int, List[int]] = {}
-        for pid in range(cfg.n_pages - 1, 0, -1):   # page 0 stays reserved
-            self._free.setdefault(self.mm.kv_page_node(pid), []).append(pid)
+        # page 0 stays reserved; pop() hands out page 1 first
+        self._free: List[int] = list(range(cfg.n_pages - 1, 0, -1))
         self._pages: Dict[int, List[int]] = {}      # seq uid -> logical order
         self._ref: Dict[int, int] = {}              # page id -> refcount
         self.prefix = PrefixCache(cfg.page_size) if prefix_cache else None
         self.retain = retain and prefix_cache
         #: cached-free LRU: prefix-indexed pages at refcount 0, oldest
         #: retirement first — reclaimed by ``_take_page`` when the free
-        #: lists run dry, revived by ``share_pages`` on a prefix hit
+        #: list runs dry, revived by ``share_pages`` on a prefix hit
         self._retained: "OrderedDict[int, None]" = OrderedDict()
         #: device page copies the engine must apply before the next
         #: forward pass: list of (src page id, dst page id)
         self.pending_copies: List[Tuple[int, int]] = []
         self.stats: Dict[str, int] = {
-            "fresh_pages": 0,      # pages handed out from the free lists
+            "fresh_pages": 0,      # pages handed out from the free list
             "shared_pages": 0,     # block-table entries served by sharing
             "cow_copies": 0,       # copy-on-write page clones
             "cached_tokens": 0,    # prompt tokens whose prefill was skipped
@@ -344,15 +326,10 @@ class KVCachePool:
         if c is not None:
             c.inc(n)
 
-    def free_pages_by_node(self) -> Dict[int, int]:
-        """Truly-free pages per node (retained pages excluded — they
-        are reclaimable but their bytes still hold cached prefixes)."""
-        return {n: len(v) for n, v in self._free.items()}
-
     # ------------------------------------------------------------------
     def n_free(self) -> int:
         """Allocatable pages: truly free + retained (reclaimable)."""
-        return sum(len(v) for v in self._free.values()) + len(self._retained)
+        return len(self._free) + len(self._retained)
 
     def n_retained(self) -> int:
         return len(self._retained)
@@ -367,15 +344,12 @@ class KVCachePool:
         need = self.cfg.pages_for(n_tokens) - len(self._pages.get(uid, []))
         return need <= self.n_free()
 
-    def _take_page(self, node_hint: int) -> int:
-        """Pop a free page, preferring the hinted node's pool; when the
-        free lists are dry, evict the least-recently-retired cached
-        page (its prefix entries die with it)."""
-        order = sorted(self._free, key=lambda n: (n != node_hint,
-                                                  -len(self._free[n]), n))
-        for node in order:
-            if self._free[node]:
-                return self._free[node].pop()
+    def _take_page(self) -> int:
+        """Pop the free list's top page; when it is empty, evict the
+        least-recently-retired cached page (its prefix entries die with
+        it)."""
+        if self._free:
+            return self._free.pop()
         if self._retained:
             pid, _ = self._retained.popitem(last=False)   # LRU order
             if self.prefix is not None:
@@ -385,7 +359,7 @@ class KVCachePool:
         raise RuntimeError("KV pool exhausted")
 
     # ------------------------------------------------------------------
-    def grow(self, uid: int, n_tokens: int, *, node_hint: int = 0) -> bool:
+    def grow(self, uid: int, n_tokens: int) -> bool:
         """Ensure ``uid``'s block table covers ``n_tokens`` token slots.
 
         Shared (prefix-adopted) pages count toward coverage, so only the
@@ -405,7 +379,7 @@ class KVCachePool:
         if need > self.n_free():
             return False
         for _ in range(need):
-            pid = self._take_page(node_hint)
+            pid = self._take_page()
             self._ref[pid] = 1
             self._stat("fresh_pages")
             pages.append(pid)
@@ -432,7 +406,7 @@ class KVCachePool:
                     continue
                 if self.prefix is not None:
                     self.prefix.forget(pid)
-                self._free[self.mm.kv_page_node(pid)].append(pid)
+                self._free.append(pid)
         if freed and self.pending_copies:
             # a queued clone whose target died (admission rollback,
             # same-step preemption) must not clobber the page's next owner
@@ -482,8 +456,7 @@ class KVCachePool:
             return PrefixMatch()
         return self.prefix.match(tokens, len(tokens) - 1)
 
-    def adopt_prefix(self, uid: int, match: PrefixMatch, *,
-                     node_hint: int = 0) -> bool:
+    def adopt_prefix(self, uid: int, match: PrefixMatch) -> bool:
         """Attach a :meth:`match_prefix` result to a fresh sequence:
         share the full pages and, on mid-page divergence, allocate the
         copy-on-write clone (queueing its device copy).  Returns False —
@@ -496,7 +469,7 @@ class KVCachePool:
         if match.pages:
             self.share_pages(uid, match.pages)
         if match.cow_src is not None:
-            dst = self._take_page(node_hint)
+            dst = self._take_page()
             self._ref[dst] = 1
             self._stat("fresh_pages")
             self._stat("cow_copies")
@@ -513,8 +486,7 @@ class KVCachePool:
         if self.prefix is not None:
             self.prefix.register(tokens, self._pages.get(uid, []))
 
-    def ensure_writable(self, uid: int, pos: int, *,
-                        node_hint: int = 0) -> bool:
+    def ensure_writable(self, uid: int, pos: int) -> bool:
         """Copy-on-write guard: make the page holding token slot ``pos``
         private to ``uid`` before it is written.  No-op for refcount-1
         pages; for shared pages, swaps in a fresh clone and queues the
@@ -529,7 +501,7 @@ class KVCachePool:
             return True
         if self.n_free() == 0:
             return False
-        dst = self._take_page(node_hint)
+        dst = self._take_page()
         self._ref[dst] = 1
         self._stat("fresh_pages")
         self._stat("cow_copies")
@@ -571,7 +543,7 @@ class KVCachePool:
                     continue
                 if self.prefix is not None:
                     self.prefix.forget(pid)
-                self._free[self.mm.kv_page_node(pid)].append(pid)
+                self._free.append(pid)
         if dropped and self.pending_copies:
             # same rule as free(): a queued clone whose target just left
             # the live set must not clobber the page's next owner
@@ -613,7 +585,7 @@ class KVCachePool:
                     continue
                 if self.prefix is not None:
                     self.prefix.forget(pid)
-                self._free[self.mm.kv_page_node(pid)].append(pid)
+                self._free.append(pid)
         if dropped and self.pending_copies:
             # same rule as free(): a queued clone whose target just left
             # the live set must not clobber the page's next owner
@@ -655,27 +627,8 @@ class KVCachePool:
     # ------------------------------------------------------------------
     # accounting
     # ------------------------------------------------------------------
-    def live_bytes_per_node(self) -> Dict[int, int]:
-        out = {n: 0 for n in self._free}
-        for pid in self._ref:
-            out[self.mm.kv_page_node(pid)] += self.cfg.page_bytes
-        return out
-
-    def capacity_bytes_per_node(self) -> Dict[int, int]:
-        """Planned (pre-allocated) KV bytes per node, from the planner's
-        pool peaks — what the node's carve-out actually reserves.  Under
-        TP this sums the node's per-shard pools (a page's bytes live 1/S
-        on each shard)."""
-        out: Dict[int, int] = {}
-        for p in self.mm.kv_pools:
-            out[p.node_id or 0] = out.get(p.node_id or 0, 0) + p.peak
-        return out
-
     def capacity_bytes_per_shard(self) -> Dict[int, int]:
-        """Planned KV bytes per mesh shard (``{0: total}`` without TP):
-        every shard reserves its head slice of every node's pages."""
-        out: Dict[int, int] = {}
-        for p in self.mm.kv_pools:
-            sid = p.shard_id or 0
-            out[sid] = out.get(sid, 0) + p.peak
-        return out
+        """Reserved KV bytes per mesh shard (``{0: total}`` without TP):
+        every shard holds its head slice of every page."""
+        return {s: self.cfg.n_pages * self.cfg.page_shard_bytes
+                for s in range(self.cfg.n_shards)}

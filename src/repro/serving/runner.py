@@ -72,7 +72,7 @@ class ModelRunner:
     paged KV cache.  No scheduling knowledge; see module docstring.
 
     ``mesh=`` switches on the **tensor-parallel mode** (the paper's §3
-    partition run over a JAX device mesh, shard ≅ NUMA node): every
+    partition run over a JAX device mesh): every
     per-layer page-pool buffer is laid out head-sharded over the
     ``model`` axis (``NamedSharding`` on the Hkv dim — each shard holds
     its head slice of *every* page), block tables upload replicated,

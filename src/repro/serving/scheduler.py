@@ -244,14 +244,6 @@ class ContinuousScheduler:
             return remaining
         return min(self.prefill_chunk, remaining)
 
-    def _slot_node(self, slot: int) -> int:
-        """Home-node hint: stripe slots across the pool's NUMA nodes so
-        each node's threads mostly touch locally-resident KV pages
-        (under TP the per-shard pools of one node count once — a page's
-        head-slices follow its node)."""
-        n = max(self.pool.mm.kv_node_count, 1)
-        return slot % n
-
     def _requeue(self, seq: Sequence) -> None:
         """Priority-then-FCFS insertion (stable): the queue is kept
         sorted by ``(priority_rank, arrival)``, so interactive traffic
@@ -265,7 +257,7 @@ class ContinuousScheduler:
                 return
         self.waiting.append(seq)
 
-    def _admit(self, seq: Sequence, slot: int) -> bool:
+    def _admit(self, seq: Sequence) -> bool:
         """Reserve KV for ``seq``'s whole prompt + one decode token,
         sharing every prefix-cached page instead of allocating it."""
         if faults.ACTIVE and faults.should_fire("pool.exhaust"):
@@ -286,10 +278,9 @@ class ContinuousScheduler:
                                if pool.refcount(p) == 0)
         if need_total - len(match.pages) > pool.n_free() - matched_retained:
             return False
-        hint = self._slot_node(slot)
-        if not pool.adopt_prefix(seq.uid, match, node_hint=hint):
+        if not pool.adopt_prefix(seq.uid, match):
             return False
-        if not pool.grow(seq.uid, len(prompt) + 1, node_hint=hint):
+        if not pool.grow(seq.uid, len(prompt) + 1):
             pool.release(seq.uid)   # roll back the adopted references
             return False
         seq.n_prefilled = match.n_tokens
@@ -348,11 +339,10 @@ class ContinuousScheduler:
             if seq.arrival > now:
                 i += 1
                 continue
-            slot = self._free_slots[-1]
-            if not self._admit(seq, slot):
+            if not self._admit(seq):
                 break
             del self.waiting[i]
-            self._free_slots.pop()
+            slot = self._free_slots.pop()
             seq.slot = slot
             if seq.t_first_sched < 0:
                 seq.t_first_sched = now
@@ -377,12 +367,10 @@ class ContinuousScheduler:
                 continue
             if seq in sched.prefills:       # reservation made at admission
                 continue
-            hint = self._slot_node(slot)
             k_eff = (lookahead_for(seq, self.spec_lookahead, self.max_len)
                      if self.spec_lookahead else 0)
-            while not (self.pool.grow(seq.uid, seq.next_pos + 1 + k_eff,
-                                      node_hint=hint)
-                       and self._writable_span(seq, k_eff, hint)):
+            while not (self.pool.grow(seq.uid, seq.next_pos + 1 + k_eff)
+                       and self._writable_span(seq, k_eff)):
                 victim = self._pick_victim(exclude=seq)
                 if victim is None:
                     raise RuntimeError(
@@ -400,7 +388,7 @@ class ContinuousScheduler:
             self._g_running.set(len(self.running))
         return sched
 
-    def _writable_span(self, seq: Sequence, k_eff: int, hint: int) -> bool:
+    def _writable_span(self, seq: Sequence, k_eff: int) -> bool:
         """Copy-on-write guard for this step's whole write span: plain
         decode writes one row at ``next_pos - 1``; a speculating step
         writes up to ``k_eff`` more (draft rows), which can cross into
@@ -412,8 +400,7 @@ class ContinuousScheduler:
         first = (seq.next_pos - 1) // ps
         last = (seq.next_pos - 1 + k_eff) // ps
         for li in range(first, last + 1):
-            if not self.pool.ensure_writable(seq.uid, li * ps,
-                                             node_hint=hint):
+            if not self.pool.ensure_writable(seq.uid, li * ps):
                 return False
         return True
 

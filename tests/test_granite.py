@@ -193,17 +193,17 @@ def test_tensor_parallel_applies_the_scalars_with_no_new_collective():
                         sampling=SamplingParams(max_new_tokens=6))
                 for i, p in enumerate({prompts()!r})]
 
-        def run(mesh=None, n_nodes=1):
+        def run(mesh=None):
             eng = ContinuousServingEngine(
                 model, params, max_len=64, max_running=2, page_size=4,
-                prefill_chunk=8, spec_decode=3, mesh=mesh, n_nodes=n_nodes)
+                prefill_chunk=8, spec_decode=3, mesh=mesh)
             toks = [c.tokens for c in eng.generate(reqs)]
             lg = eng.core.runner.decode(np.array([[5], [7]], np.int32),
                                         np.array([3, -1], np.int32))
             return eng, toks, np.asarray(lg)
 
         _, want, want_lg = run()
-        eng, got, got_lg = run(make_mesh((2,), ("model",)), n_nodes=2)
+        eng, got, got_lg = run(make_mesh((2,), ("model",)))
         assert got == want, (got, want)
         np.testing.assert_allclose(got_lg, want_lg, rtol=0, atol=1e-6)
         r = eng.core.runner

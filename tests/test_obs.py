@@ -393,17 +393,15 @@ def test_cancel_emits_cancelled_event(tiny):
 def test_pool_gauges_sampled_per_step(tiny):
     clock = VirtualClock()
     core = _fake_core(tiny, clock, max_len=64, max_running=2,
-                      page_size=8, n_nodes=2, prefix_cache=False)
+                      page_size=8, prefix_cache=False)
     core.submit(Request(uid=0, prompt=list(range(1, 9)),
                         sampling=SamplingParams(max_new_tokens=2)))
     _drain(core, clock)
     snap = core.registry.snapshot()
-    assert require_gauge(snap, "kv_pool.pages_free",
-                         ["node", "shard"]) == []
-    free = {(g["labels"]["node"], g["labels"]["shard"]): g["value"]
-            for g in snap["gauges"] if g["name"] == "kv_pool.pages_free"}
-    assert set(free) == {("0", "0"), ("1", "0")}
-    assert sum(free.values()) == core.pool.n_free() - core.pool.n_retained()
+    assert require_gauge(snap, "kv_pool.pages_free", []) == []
+    free = [g for g in snap["gauges"] if g["name"] == "kv_pool.pages_free"]
+    assert [g["labels"] for g in free] == [{}]
+    assert free[0]["value"] == core.pool.n_free() - core.pool.n_retained()
 
 
 class _SpanRecorder:

@@ -21,10 +21,10 @@ from repro.serving import (ContinuousServingEngine, ContinuousScheduler,
                            SamplingParams)
 
 
-def _pool(n_pages=17, page_size=4, n_nodes=1, prefix_cache=True):
+def _pool(n_pages=17, page_size=4, prefix_cache=True):
     return KVCachePool(KVPoolConfig(
         n_pages=n_pages, page_size=page_size, n_layers=2, n_kv_heads=2,
-        head_dim=8, dtype_bytes=4, n_nodes=n_nodes),
+        head_dim=8, dtype_bytes=4),
         prefix_cache=prefix_cache)
 
 
@@ -98,7 +98,7 @@ class TestRefcounts:
                     pool.share_pages(uid, pool.block_table(donor)[:1])
             else:
                 pool.release(uid)
-            free = {p for lst in pool._free.values() for p in lst}
+            free = set(pool._free)
             held = {}
             for u in range(3):
                 for p in pool.block_table(u):

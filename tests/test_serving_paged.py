@@ -1,6 +1,7 @@
 """Paged KV pool, continuous-batching scheduler + engine (PR: serving).
 
-Property tests: page aliasing, free-list reuse, NUMA byte accounting.
+Property tests: page aliasing, free-list reuse, fixed page ids, byte
+accounting.
 System tests: greedy token parity with the bucket engine (including
 under forced preemption), late-arrival admission without recompiling
 the decode step, paged Pallas kernel vs jnp oracle.
@@ -12,17 +13,16 @@ import numpy as np
 import pytest
 from _hypothesis_compat import given, settings, st
 
-from repro.core.memory import MemoryManager, _align
 from repro.models import ModelConfig, build_model
 from repro.serving import (ContinuousServingEngine, ContinuousScheduler,
                            KVCachePool, KVPoolConfig, Request,
                            SamplingParams, ServingEngine)
 
 
-def _pool(n_pages=17, page_size=4, n_nodes=1, numa=True):
+def _pool(n_pages=17, page_size=4):
     return KVCachePool(KVPoolConfig(
         n_pages=n_pages, page_size=page_size, n_layers=2, n_kv_heads=2,
-        head_dim=8, dtype_bytes=4, n_nodes=n_nodes, numa=numa))
+        head_dim=8, dtype_bytes=4))
 
 
 class TestKVPool:
@@ -39,7 +39,7 @@ class TestKVPool:
     def test_pages_never_alias_across_live_sequences(self, ops):
         """Random grow/free interleavings: a physical page is owned by
         at most one live sequence, and ownership matches block tables."""
-        pool = _pool(n_pages=13, n_nodes=2)
+        pool = _pool(n_pages=13)
         lens = {}
         for i, op in enumerate(ops):
             uid = op % 3
@@ -71,41 +71,71 @@ class TestKVPool:
         # LIFO: the most recently freed (cache-warm) page comes first
         assert pool.block_table(1)[0] == first[-1]
 
-    def test_per_node_accounting_matches_memory_manager(self):
+    def test_capacity_and_live_bytes_accounting(self):
         cfg = KVPoolConfig(n_pages=12, page_size=4, n_layers=3,
-                           n_kv_heads=2, head_dim=8, dtype_bytes=4,
-                           n_nodes=4, numa=True)
+                           n_kv_heads=2, head_dim=8, dtype_bytes=4)
         pool = KVCachePool(cfg)
-        cap = pool.capacity_bytes_per_node()
-        # planner view: per-node totals of the shared MemoryManager
-        assert {n: b for n, b in pool.mm.per_node_bytes().items() if b} \
-            == {n: b for n, b in cap.items() if b}
-        # 12 pages round-robin over 4 nodes = 3 aligned carve-outs each
-        assert all(b == 3 * _align(cfg.page_bytes) for b in cap.values())
-        # home-node allocation: node 0's usable pages (2 — one of its 3
-        # carve-outs is the scratch page) go first, then spill to the
-        # fullest other free-lists
-        pool.grow(0, 16, node_hint=0)    # 4 pages
-        live = pool.live_bytes_per_node()
-        assert sum(live.values()) == 4 * cfg.page_bytes
-        assert live[0] == 2 * cfg.page_bytes, "home node filled first"
-        assert all(live[n] <= cap[n] for n in live)
+        assert pool.capacity_bytes_per_shard() == {0: 12 * cfg.page_bytes}
+        assert pool.grow(0, 16)          # 4 pages
+        assert pool.n_live() * cfg.page_bytes == 4 * cfg.page_bytes
+        assert pool.n_live() + pool.n_free() == cfg.n_pages - 1
 
-    def test_kv_pages_sit_alongside_weights_in_one_plan(self):
-        """KV pages extend the same planner as weights/activations."""
-        from repro.core.tensor import OpType, make_header
-        mm = MemoryManager(2, numa=True)
-        for i in range(4):
-            mm.place_weight(make_header((64,), np.float32, op=OpType.WEIGHT,
-                                        name=f"w{i}", node_id=i % 2))
-        cfg = KVPoolConfig(n_pages=4, page_size=4, n_layers=2, n_kv_heads=2,
-                           head_dim=8, n_nodes=2, numa=True)
-        KVCachePool(cfg, mm=mm)
-        per_node = mm.per_node_bytes()
-        want_w = 2 * _align(64 * 4)
-        want_kv = 2 * _align(cfg.page_bytes)
-        assert per_node == {0: want_w + want_kv, 1: want_w + want_kv}
-        assert mm.total_bytes() == 2 * (want_w + want_kv)
+    def test_page_script_hands_out_fixed_ids(self):
+        """The allocation order is part of the device contract: block
+        tables, and so every program's page walk, follow these ids."""
+        assert page_script() == PAGE_SCRIPT_IDS
+
+    @pytest.mark.parametrize("n_shards", [1, 2, 4])
+    def test_page_ids_do_not_depend_on_shard_count(self, n_shards):
+        assert page_script(n_shards) == page_script(1)
+
+    @pytest.mark.parametrize("n_shards", [1, 2, 4])
+    def test_capacity_per_shard_is_its_head_slice_of_every_page(
+            self, n_shards):
+        cfg = KVPoolConfig(n_pages=9, page_size=4, n_layers=2,
+                           n_kv_heads=4, head_dim=8, dtype_bytes=4,
+                           n_shards=n_shards)
+        assert KVCachePool(cfg).capacity_bytes_per_shard() == {
+            s: 9 * cfg.page_shard_bytes for s in range(n_shards)}
+        assert cfg.page_shard_bytes * n_shards == cfg.page_bytes
+
+
+def page_script(n_shards=1):
+    """A fixed grow / prefix-share / CoW / truncate / release /
+    release_below / retained-eviction sequence on a fresh pool; returns
+    the block tables and page copies it produced, in order."""
+    pool = KVCachePool(KVPoolConfig(
+        n_pages=13, page_size=4, n_layers=2, n_kv_heads=4, head_dim=8,
+        dtype_bytes=4, n_shards=n_shards))
+    out = []
+    a = list(range(100, 110))
+    assert pool.grow(0, len(a) + 1)
+    pool.register_prefix(0, a)
+    out.append(pool.block_table(0))
+    b = a[:6] + [7, 7, 7, 7]             # diverges inside block 1: CoW
+    assert pool.adopt_prefix(1, pool.match_prefix(b))
+    assert pool.grow(1, len(b) + 1)
+    out.append(pool.block_table(1))
+    out.append(pool.drain_copies())
+    assert pool.grow(2, 8)
+    pool.share_pages(3, pool.block_table(2)[:1])
+    assert pool.grow(3, 8)
+    assert pool.ensure_writable(3, 1)    # its first page is shared: CoW
+    out.append(pool.block_table(3))
+    out.append(pool.drain_copies())
+    pool.truncate_to(1, 5)
+    pool.release(2)
+    pool.release_below(3, 4)
+    assert pool.grow(4, 12)              # LIFO: the pages just freed
+    out.append(pool.block_table(4))
+    pool.release(0)                      # its indexed page is retained
+    assert pool.grow(5, 4 * pool.n_free())   # drains, then evicts it
+    out.append(pool.block_table(5))
+    return out
+
+
+PAGE_SCRIPT_IDS = [[1, 2, 3], [1, 4, 5], [(2, 4)], [9, 8], [(6, 9)],
+                   [9, 7, 6], [3, 5, 10, 11, 12, 2]]
 
 
 class TestScheduler:

@@ -1,8 +1,8 @@
 """Tensor-parallel paged serving (PR: TP mesh over the paged engine).
 
-Host level (fast lane): shard-striped KV page planning — per-(node,
-shard) pools, byte accounting, placement — and the sliding-window
-``release_below`` recycling groundwork (property-tested).
+Host level (fast lane): head-sharded KV pool validation and per-shard
+byte accounting, and the sliding-window ``release_below`` recycling
+groundwork (property-tested).
 
 Device level (subprocess, ``slow``): everything needing a real
 multi-device mesh runs in a child interpreter with forced host devices
@@ -27,7 +27,6 @@ import textwrap
 import pytest
 from _hypothesis_compat import given, settings, st
 
-from repro.core.memory import MemoryManager
 from repro.serving import KVCachePool, KVPoolConfig
 
 
@@ -46,62 +45,31 @@ def _run(snippet: str, devices: int = 2) -> str:
 
 
 # ----------------------------------------------------------------------
-# host-side planning (fast lane)
+# head-sharded pool (fast lane)
 # ----------------------------------------------------------------------
 
-class TestShardStripedPlanning:
-    def test_per_node_per_shard_pools_split_page_bytes(self):
-        mm = MemoryManager(2, numa=True)
-        mm.plan_kv_pages(8, page_bytes=1024, n_shards=4)
-        assert len(mm.kv_pools) == 2 * 4
-        assert mm.kv_node_count == 2
-        assert mm.kv_shard_count == 4
-        # every shard of a node reserves the same bytes: its head slice
-        # of each of the node's pages (4 pages x 1024/4, 128-aligned)
-        peaks = {p.name: p.peak for p in mm.kv_pools}
-        assert len(set(peaks.values())) == 1
-        assert all(p.peak == 4 * 256 for p in mm.kv_pools)
-
-    def test_node_striping_is_shard_invariant(self):
-        flat = MemoryManager(4, numa=True)
-        flat.plan_kv_pages(16, page_bytes=512)
-        tp = MemoryManager(4, numa=True)
-        tp.plan_kv_pages(16, page_bytes=512, n_shards=2)
-        for pid in range(16):
-            assert flat.kv_page_node(pid) == tp.kv_page_node(pid)
-        node, shards = tp.kv_page_placement(5)
-        assert node == tp.kv_page_node(5)
-        assert shards == (0, 1)         # bytes live on every shard
-        assert flat.kv_page_placement(5) == (flat.kv_page_node(5), (0,))
-
-    def test_page_bytes_must_split_over_shards(self):
-        mm = MemoryManager(1, numa=False)
-        with pytest.raises(ValueError, match="split"):
-            mm.plan_kv_pages(4, page_bytes=1000, n_shards=3)
-
-    def test_pool_config_validates_kv_head_divisibility(self):
+class TestShardedPool:
+    @pytest.mark.parametrize("n_kv_heads,n_shards", [(2, 4), (4, 3)])
+    def test_pool_config_validates_kv_head_divisibility(
+            self, n_kv_heads, n_shards):
         with pytest.raises(ValueError, match="head-shard"):
             KVCachePool(KVPoolConfig(
-                n_pages=9, page_size=4, n_layers=2, n_kv_heads=2,
-                head_dim=8, n_shards=4))
+                n_pages=9, page_size=4, n_layers=2, n_kv_heads=n_kv_heads,
+                head_dim=8, n_shards=n_shards))
 
-    def test_pool_shard_accounting_and_node_hints(self):
+    def test_pool_shard_accounting(self):
         pool = KVCachePool(KVPoolConfig(
             n_pages=9, page_size=4, n_layers=2, n_kv_heads=4,
-            head_dim=8, dtype_bytes=4, n_nodes=2, n_shards=2))
+            head_dim=8, dtype_bytes=4, n_shards=2))
         assert pool.cfg.page_shard_bytes * 2 == pool.cfg.page_bytes
         per_shard = pool.capacity_bytes_per_shard()
-        assert set(per_shard) == {0, 1}
-        assert per_shard[0] == per_shard[1]
-        per_node = pool.capacity_bytes_per_node()
-        assert sum(per_node.values()) == sum(per_shard.values())
-        # free lists stripe by NODE (a page's head-slices follow its
-        # node), so both node pools hand out pages
-        assert pool.grow(0, 16, node_hint=0)
-        assert pool.grow(1, 16, node_hint=1)
-        nodes = {pool.mm.kv_page_node(p)
-                 for uid in (0, 1) for p in pool.block_table(uid)}
-        assert nodes == {0, 1}
+        assert per_shard == {0: 9 * pool.cfg.page_shard_bytes,
+                             1: 9 * pool.cfg.page_shard_bytes}
+        assert sum(per_shard.values()) == 9 * pool.cfg.page_bytes
+        # page ids are global: the free list hands out the one-shard order
+        assert pool.grow(0, 16) and pool.grow(1, 16)
+        assert pool.block_table(0) == [1, 2, 3, 4]
+        assert pool.block_table(1) == [5, 6, 7, 8]
 
 
 # ----------------------------------------------------------------------
@@ -229,10 +197,10 @@ def test_tp_greedy_parity_incl_prefix_cow_chunked():
                      sampling=SamplingParams(max_new_tokens=8))
              for i in range(2)]
 
-    def run(mesh=None, n_nodes=1):
+    def run(mesh=None):
         eng = ContinuousServingEngine(
             model, params, max_len=64, max_running=4, page_size=8,
-            prefill_chunk=8, mesh=mesh, n_nodes=n_nodes)
+            prefill_chunk=8, mesh=mesh)
         toks = [c.tokens for c in eng.generate(wave1)]
         toks += [c.tokens for c in eng.generate(wave2)]
         return eng, toks
@@ -240,7 +208,7 @@ def test_tp_greedy_parity_incl_prefix_cow_chunked():
     _, ref = run()
     for shards in (1, 2):
         mesh = make_mesh((shards,), ("model",))
-        eng, got = run(mesh, n_nodes=shards)
+        eng, got = run(mesh)
         assert got == ref, (shards, got, ref)
         st = eng.pool.stats
         assert st["shared_pages"] > 0, st      # prefix pages shared

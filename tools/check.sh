@@ -137,7 +137,7 @@ python -m repro.launch.serve --arch qwen3-1.7b --engine async \
     --metrics-json "$OBS_TMP/metrics.json" --trace "$OBS_TMP/trace.jsonl"
 python -m repro.obs.validate --metrics "$OBS_TMP/metrics.json" \
     --trace "$OBS_TMP/trace.jsonl" \
-    --require-gauge kv_pool.pages_free:node,shard
+    --require-gauge kv_pool.pages_free
 echo "== serving smoke: self-speculative decoding (async, k=4) =="
 python -m repro.launch.serve --arch qwen3-1.7b --engine async \
     --spec-decode 4 --max-new 8 --max-running 4 --page-size 8 \
